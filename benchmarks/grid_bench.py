@@ -9,9 +9,8 @@ record with the measured speedup.
 
 ``bench_pallas`` times the two grid *backends* against each other — the
 XLA vmapped ``lax.switch`` scan vs the fused Pallas scenario-grid kernel
-(interpret mode on this CPU container; the same structure compiles for TPU
-lanes) — at N in {64, 256, 1024} scenarios, and writes
-``BENCH_grid_pallas.json``.
+(the Pallas interpreter on the CPU, a Mosaic kernel on a TPU) — at N in
+{64, 256, 1024} scenarios, and writes ``BENCH_grid_pallas.json``.
 
 ``bench_stream`` times the two result *modes* end to end through
 ``simulate_grid`` — the [N, T]-series path (device series + f64 host
@@ -27,7 +26,9 @@ scenario blocks, so 65536 scenarios complete on this CPU container.
 policy-uniform block dispatch of ``core.simulate._grid_agg_dispatch``,
 single-device and over a 1/2/4-device scenario mesh — at N in
 {65536, 262144, 1048576} full-year scenarios, and writes
-``BENCH_grid_shard.json``. On this 1-core CPU container the fake host
+``BENCH_grid_shard.json``. The mesh rows need four devices: on the CPU,
+export ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` before
+running the sweep. On a 1-core CPU container the fake host
 devices share the core, so the mesh rows document the sharded
 *structure* (and its bit-parity with the one-device engine); the
 single-device row is the wall-clock number, measured against the prior
@@ -64,17 +65,9 @@ sweep under ``REPRO_OBS=1`` additionally surfaces the engine's own
 from __future__ import annotations
 
 import json
-import os
 import pathlib
 import sys
 from typing import Dict, List
-
-# the shard/device sweeps need multiple host devices, and XLA only reads
-# this before its first backend init — so it must be set before jax
-# imports anywhere in the process (harmless for every other sweep)
-if {"shard", "grid-shard", "device", "grid-device"} & set(sys.argv[1:]):
-    os.environ.setdefault("XLA_FLAGS",
-                          "--xla_force_host_platform_device_count=4")
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +79,7 @@ from repro.core.slo import SLO
 from repro.core.traffic import TrafficModel
 from repro.core.twin import (QuickscalingTwin, SimpleTwin, make_twin,
                              policy_onehot, registry_version)
+from repro.kernels.ops import interpret_mode
 from repro.kernels.policy_scan import policy_grid_scan
 
 N_TWINS = 8
@@ -192,10 +186,10 @@ def _time_best(fn, repeats: int = REPEATS,
 def bench_pallas(sizes=PALLAS_SIZES, repeats: int = REPEATS) -> Dict:
     """XLA vmapped-switch backend vs fused Pallas scenario-grid kernel.
 
-    On this CPU container the kernel runs in interpret mode, so the
-    numbers measure the fused-scan structure (one pallas_call, scenarios
-    on lanes, carry resident) rather than TPU silicon; parity with the
-    XLA path is asserted on every size before timing.
+    On the CPU the kernel runs in the Pallas interpreter, so the numbers
+    there measure the fused-scan structure (one pallas_call, scenarios on
+    lanes, carry resident) rather than TPU silicon; parity with the XLA
+    path is asserted on every size before timing.
     """
     rows = []
     for n in sizes:
@@ -212,13 +206,11 @@ def bench_pallas(sizes=PALLAS_SIZES, repeats: int = REPEATS) -> Dict:
 
         def pallas():
             jax.block_until_ready(
-                policy_grid_scan(loads_j, params_j, onehot_j, 1.0,
-                                 interpret=True))
+                policy_grid_scan(loads_j, params_j, onehot_j, 1.0))
 
         # parity first (1e-5 relative on every series), then wall-clock
         _, outs_x = _grid_scan_xla(loads_j, params_j, idx_j, ver, 1.0)
-        _, outs_p = policy_grid_scan(loads_j, params_j, onehot_j, 1.0,
-                                     interpret=True)
+        _, outs_p = policy_grid_scan(loads_j, params_j, onehot_j, 1.0)
         for a, b in zip(outs_x, outs_p):
             np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                        rtol=1e-5, atol=1e-5)
@@ -227,10 +219,11 @@ def bench_pallas(sizes=PALLAS_SIZES, repeats: int = REPEATS) -> Dict:
         pallas_ms = _time_best(pallas, repeats)
         rows.append({"scenarios": n, "hours": int(loads.shape[1]),
                      "xla_ms": round(xla_ms, 3),
-                     "pallas_interpret_ms": round(pallas_ms, 3),
+                     "pallas_ms": round(pallas_ms, 3),
                      "pallas_over_xla": round(pallas_ms / xla_ms, 3)})
     out = {"device": jax.devices()[0].platform, "repeats": repeats,
-           "mode": "interpret", "parity_rtol": 1e-5, "sizes": rows}
+           "mode": "interpret" if interpret_mode() else "mosaic",
+           "parity_rtol": 1e-5, "sizes": rows}
     BENCH_JSON.write_text(json.dumps(out, indent=2, sort_keys=True) + "\n")
     return out
 
@@ -507,8 +500,8 @@ def main_pallas() -> List[str]:
         n = row["scenarios"]
         lines.append(f"grid/xla_{n}x,{row['xla_ms'] * 1e3:.0f},"
                      f"vmapped-switch-scan")
-        lines.append(f"grid/pallas_{n}x,{row['pallas_interpret_ms'] * 1e3:.0f},"
-                     f"interpret;ratio={row['pallas_over_xla']}")
+        lines.append(f"grid/pallas_{n}x,{row['pallas_ms'] * 1e3:.0f},"
+                     f"{r['mode']};ratio={row['pallas_over_xla']}")
     lines.append(f"grid/pallas_json,0,wrote={BENCH_JSON.name}")
     return lines
 

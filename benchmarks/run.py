@@ -9,15 +9,14 @@ simulations), table3 (engineering comparison), table4 (retention costs),
 plus the roofline table over the assigned (arch x shape) grid, a core
 micro-benchmark of the wind-tunnel primitives, the twin-calibration
 fit benchmark (which also writes BENCH_calibrate.json), the
-grid-backend sweep ``grid-pallas`` — XLA vs Pallas-interpret at
+grid-backend sweep ``grid-pallas`` — XLA vs the Pallas kernel at
 64/256/1024 scenarios (writes BENCH_grid_pallas.json) — and the
 streaming sweep ``grid-stream`` — series vs aggregate ``simulate_grid``
 at 1024/8192/65536 full-year scenarios (writes BENCH_grid_stream.json) —
 the sharded-engine sweep ``grid-shard`` — the policy-uniform block
 engine at 65536/262144/1048576 full-year scenarios over a 1/2/4-device
-scenario mesh (writes BENCH_grid_shard.json; run with
-``XLA_FLAGS=--xla_force_host_platform_device_count=4`` or pass
-``grid-shard`` on the command line, which sets it before jax loads) —
+scenario mesh (writes BENCH_grid_shard.json; on the CPU run with
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``) —
 the device-resident histogram sweep ``grid-device`` — the fully
 in-graph aggregate engine (f64 ``segment_sum`` histogram, no host
 binning, duplicate scenario rows deduped at dispatch) at
@@ -93,20 +92,27 @@ TABLES = {
 }
 
 
-def main() -> None:
+def main() -> int:
+    """Run the named tables (all by default); 1 if any table failed."""
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     which = sys.argv[1:] or list(TABLES)
     print("name,us_per_call,derived")
+    failed = []
     for name in which:
         fn = TABLES.get(name)
         if fn is None:
             print(f"{name},0,unknown-table")
+            failed.append(name)
             continue
         try:
             for line in fn():
                 print(line, flush=True)
         except Exception as e:   # noqa: BLE001 — report, keep going
             print(f"{name}/error,0,{type(e).__name__}:{str(e)[:120]}")
+            failed.append(name)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

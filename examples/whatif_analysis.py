@@ -10,8 +10,8 @@ driven by the unified TwinPolicy engine (one vmapped scan per grid):
      on the same traffic, priced per instance.
   4. The same policy sweep re-run on the fused Pallas grid backend
      (``kernels.ops.pallas_mode()``): scenarios ride the vector lanes of
-     one kernel instead of the XLA vmapped lax.switch scan — interpret
-     mode on CPU, the TPU layout on real hardware — and the Table II
+     one kernel instead of the XLA vmapped lax.switch scan — the Pallas
+     interpreter on the CPU, a Mosaic kernel on a TPU — and the Table II
      numbers agree to 1e-5.
   5. A 4096-scenario cost-lever sweep (autoscaling delay x instance cap x
      queue cap x batch window x growth — the levers Jablonski & Heltweg
@@ -127,7 +127,7 @@ print("a slow autoscaler (6h boot) clears the fifo backlog for less than "
 # ---------------------------------------------------------------------------
 from repro.kernels.ops import pallas_mode  # noqa: E402
 
-with pallas_mode():         # interpret=True: CPU-safe, same TPU structure
+with pallas_mode():         # interpreted on the CPU, Mosaic on a TPU
     psims_pallas = run_grid(policy_twins, [nominal, high], slo=slo)
 print(render_table(table2_rows(psims_pallas),
                    "What-if #4: same sweep, Pallas grid backend"))

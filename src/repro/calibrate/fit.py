@@ -185,8 +185,6 @@ def _sharded_fit_fn(devices: int, steps: int, dt_hours: float, version: int,
     tests/test_stream_objectives.py."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.distributed.sharding import shard_map
-
     mesh = Mesh(np.asarray(jax.devices()[:devices]), ("restart",))
     shard, rep = P("restart"), P()
 
@@ -198,8 +196,8 @@ def _sharded_fit_fn(devices: int, steps: int, dt_hours: float, version: int,
 
     in_specs = (shard,) + (rep,) * 10
     out_specs = (shard, shard, P(None, "restart"))
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _as_operands(trace: ObservedTrace, weights: Optional[Dict[str, float]]):

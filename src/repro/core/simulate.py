@@ -34,8 +34,9 @@ The grid runs on either of two interchangeable backends, selected by
   ``kernels/policy_scan.py``: one ``pallas_call`` scans all T bins for
   LANES scenarios at a time using the branchless lane-vectorized policy
   steps (``core.twin.lane_policy_step``), scenarios on the vector lanes,
-  ``interpret=True`` on CPU. Grids and K-restart calibration fits
-  (restarts are just more lanes) both route through this selection.
+  Mosaic-compiled on a TPU, interpreted on the CPU. Grids and K-restart
+  calibration fits (restarts are just more lanes) both route through
+  this selection.
 
 Each backend additionally exists in a **streaming-aggregate** variant
 (``simulate_grid(return_series=False)`` -> ``_grid_scan_agg``): the
@@ -76,7 +77,6 @@ from repro import obs
 from repro.core.cost import CostModel
 from repro.core.slo import SLO
 from repro.core.traffic import DAYS_PER_YEAR, HOURS_PER_YEAR, MONTH_DAYS
-from jax.experimental import enable_x64
 
 from repro.core.twin import (A_COST, A_DROP, A_FLTH, A_FOKH, A_LATW, A_LOAD,
                              A_MAXP, A_OKH, A_OKW, A_PROC, AGG_DIM,
@@ -233,7 +233,7 @@ def _grid_scan(loads: jnp.ndarray, params: jnp.ndarray,
     Default: the XLA vmapped switch-scan above. Under ``kernels.ops.
     use_pallas(True)`` / ``pallas_mode()``: the fused Pallas scenario-grid
     kernel (``kernels/policy_scan.py``), scenarios on the vector lanes,
-    ``interpret=True`` on CPU. Same operands, same (q_end [N], five
+    interpreted on the CPU. Same operands, same (q_end [N], five
     [N, T] series) contract either way; selection happens OUTSIDE jit, so
     flipping the switch between calls never stales a trace cache.
     """
@@ -262,9 +262,9 @@ def _agg_time_chunk(t_bins: int, cap: int = 1024) -> int:
 def _branches_f32():
     """``policy_branches()`` with every step output pinned to f32.
 
-    The aggregate XLA jits trace under ``enable_x64()`` (the histogram's
-    exactness contract), where a registered policy step that builds
-    dtype-less literals (e.g. ``jnp.zeros(())``) silently emits f64 —
+    The aggregate XLA jits trace under ``jax.enable_x64(True)`` (the
+    histogram's exactness contract), where a registered policy step that
+    builds dtype-less literals (e.g. ``jnp.zeros(())``) silently emits f64 —
     breaking ``lax.switch`` branch-type agreement and flipping scan-carry
     dtypes mid-trace. Registry steps are f32-in/f32-out by contract;
     this enforces the contract at the trace boundary instead of trusting
@@ -299,7 +299,7 @@ def _agg_scan_vmap(loads: jnp.ndarray, params: jnp.ndarray,
     ``segment_sum`` accumulated OUTSIDE the scan carry, entirely on
     device, bit-identical to host ``np.bincount``. No [N, T] panel is
     ever staged and nothing round-trips to the host. MUST be traced
-    under ``jax.experimental.enable_x64()`` (``_grid_scan_agg`` wraps
+    under ``jax.enable_x64(True)`` (``_grid_scan_agg`` wraps
     its call sites). Returns (carry_end [N, CARRY_DIM],
     agg [N, AGG_DIM] f32)."""
     branches = _branches_f32()
@@ -345,7 +345,7 @@ def _grid_scan_agg_xla(loads: jnp.ndarray, params: jnp.ndarray,
     """The XLA aggregate backend (jitted). ``slo_limit`` / ``slo_mode``
     are static like ``dt_hours`` — a grid sweep reuses one SLO, so the
     retrace per distinct objective is paid once. Call under
-    ``enable_x64()`` (see ``_agg_scan_vmap``). Returns (carry_end
+    ``jax.enable_x64(True)`` (see ``_agg_scan_vmap``). Returns (carry_end
     [N, CARRY_DIM], agg [N, AGG_DIM])."""
     return _agg_scan_vmap(loads, params, policy_idx, dt_hours, slo_limit,
                           slo_mode)
@@ -410,7 +410,7 @@ def _grid_scan_agg_fault_xla(loads: jnp.ndarray, caps: jnp.ndarray,
     series), the in-carry counters gain the A_FLTH/A_FOKH attribution
     slots, and the fault backlog residue folds into ``carry_end[:, 0]``.
     Same chunked device-resident histogram contract as the benign path
-    (call under ``enable_x64()``); returns (carry_end [N, CARRY_DIM],
+    (call under ``jax.enable_x64(True)``); returns (carry_end [N, CARRY_DIM],
     agg [N, AGG_DIM])."""
     branches = _branches_f32()
     dt = jnp.asarray(dt_hours, jnp.float32)
@@ -465,7 +465,7 @@ def _grid_scan_agg(loads: jnp.ndarray, params: jnp.ndarray,
     and fully device-resident — histogram included, no host binning
     round-trip on any backend: (carry_end [N, CARRY_DIM],
     agg [N, AGG_DIM]). The XLA jits are always entered under
-    ``enable_x64()`` so their exact-f64 histogram accumulation never
+    ``jax.enable_x64(True)`` so their exact-f64 histogram accumulation never
     silently re-traces truncated. ``caps``/``fmask`` [N, T] (together)
     thread a fault schedule through either backend."""
     from repro.kernels import ops
@@ -475,7 +475,7 @@ def _grid_scan_agg(loads: jnp.ndarray, params: jnp.ndarray,
         return ops.policy_scan_agg(loads, params, onehot, dt_hours,
                                    slo_limit=slo_limit, slo_mode=slo_mode,
                                    caps=caps, fmask=fmask)
-    with enable_x64():
+    with jax.enable_x64(True):
         if caps is not None:
             return _grid_scan_agg_fault_xla(
                 loads, caps, fmask, params, policy_idx, version, dt_hours,
@@ -502,7 +502,7 @@ def _agg_scan_uniform(load_matrix: jnp.ndarray, lidx: jnp.ndarray,
     gathers ONE [B, chunk] slice per time chunk in-graph — the block's
     full [B, T] loads never exist, on device or host, and the histogram
     accumulates on device (``device_latency_histogram``; call under
-    ``enable_x64()``). Returns (carry_end [B, CARRY_DIM],
+    ``jax.enable_x64(True)``). Returns (carry_end [B, CARRY_DIM],
     agg [B, AGG_DIM])."""
     branches = _branches_f32()
     dt = jnp.asarray(dt_hours, jnp.float32)
@@ -642,7 +642,8 @@ def _agg_block_step_xla(version: int, dt_hours: float, slo_limit: float,
     device memory stays at ONE chunk's gathered loads + the O(N)
     aggregates no matter how many blocks stream through; no [B, T] panel
     ever exists and nothing returns to the host until the last block.
-    Traces f64 (the histogram segment_sum) — call under ``enable_x64()``.
+    Traces f64 (the histogram segment_sum) — call under
+    ``jax.enable_x64(True)``.
     Fault grids add the replicated [F, T] capacity/mask matrices + the
     block's [B] ``fidx`` gather map (appended AFTER ``offset`` so the
     donated accumulator positions never move)."""
@@ -660,10 +661,10 @@ def _agg_block_step_xla(version: int, dt_hours: float, slo_limit: float,
     return carry_acc, agg_acc
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4),
-                   donate_argnums=(9, 10))
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3),
+                   donate_argnums=(8, 9))
 def _agg_block_step_pallas(version: int, dt_hours: float, slo_limit: float,
-                           slo_mode: int, interpret: bool,
+                           slo_mode: int,
                            matrix_t: jnp.ndarray, lidx: jnp.ndarray,
                            params: jnp.ndarray, policy_index: jnp.ndarray,
                            carry_acc: jnp.ndarray, agg_acc: jnp.ndarray,
@@ -692,8 +693,8 @@ def _agg_block_step_pallas(version: int, dt_hours: float, slo_limit: float,
         (lidx.shape[0], num_policies()))
     carry, agg = policy_grid_agg(
         None, params, onehot, dt_hours, slo_limit=slo_limit,
-        slo_mode=slo_mode, interpret=interpret, loads_t=loads_t,
-        caps_t=caps_t, fmask_t=fmask_t, finalize=False)
+        slo_mode=slo_mode, loads_t=loads_t, caps_t=caps_t,
+        fmask_t=fmask_t, finalize=False)
     carry_acc = jax.lax.dynamic_update_slice(carry_acc, carry, (offset, 0))
     agg_acc = jax.lax.dynamic_update_slice(agg_acc, agg, (offset, 0))
     return carry_acc, agg_acc
@@ -773,7 +774,7 @@ def _agg_block_plan(policy_idx: np.ndarray, block: int):
 @functools.lru_cache(maxsize=16)
 def _sharded_agg_fn(devices: int, version: int, dt_hours: float,
                     slo_limit: float, slo_mode: int, backend: str,
-                    interpret: bool, block: int, faulted: bool = False):
+                    block: int, faulted: bool = False):
     """Build (and cache) the jitted ``shard_map`` ROUND step for a
     ``devices``-wide 1-D scenario mesh: the [K, T] load matrix is
     replicated, and one round feeds each device exactly one
@@ -789,14 +790,12 @@ def _sharded_agg_fn(devices: int, version: int, dt_hours: float,
     [D, B, AGG_KDIM] rows for one end-of-grid recombination. The old
     per-round host drain — and the pure_callback-deadlock constraint it
     was built around — is gone: the XLA round traces f64, so CALL IT
-    UNDER ``enable_x64()``. ``faulted`` builds the fault-grid variant:
-    the [F, T] capacity/mask matrices replicate like the load matrix and
-    a sharded [D, B] fault index gathers each block's per-bin fault
-    series."""
+    UNDER ``jax.enable_x64(True)``. ``faulted`` builds the fault-grid
+    variant: the [F, T] capacity/mask matrices replicate like the load
+    matrix and a sharded [D, B] fault index gathers each block's per-bin
+    fault series."""
     del version
     from jax.sharding import Mesh, PartitionSpec as P
-
-    from repro.distributed.sharding import shard_map
 
     mesh = Mesh(np.asarray(jax.devices()[:devices]), ("scenario",))
 
@@ -818,8 +817,8 @@ def _sharded_agg_fn(devices: int, version: int, dt_hours: float,
                 (block, num_policies()))
             carry, agg = policy_grid_agg(
                 None, p_b, onehot, dt_hours, slo_limit=slo_limit,
-                slo_mode=slo_mode, interpret=interpret, loads_t=loads_t,
-                caps_t=caps_t, fmask_t=fmask_t, finalize=False)
+                slo_mode=slo_mode, loads_t=loads_t, caps_t=caps_t,
+                fmask_t=fmask_t, finalize=False)
             return carry[None], agg[None]
         if faulted:
             carry, agg = _agg_scan_uniform_fault(
@@ -835,7 +834,7 @@ def _sharded_agg_fn(devices: int, version: int, dt_hours: float,
     in_specs = (P(), P("scenario"), P("scenario"), P("scenario"))
     if faulted:
         in_specs = in_specs + (P(), P(), P("scenario"))
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -847,7 +846,7 @@ def _run_blocks_sharded(load_matrix: np.ndarray, lidx: np.ndarray,
                         params: np.ndarray, block_policy: np.ndarray,
                         devices: int, version: int, dt_hours: float,
                         slo_limit: float, slo_mode: int, backend: str,
-                        interpret: bool, fault=None):
+                        fault=None):
     """Drive the sharded round step over all blocks: rounds of one block
     per device, every round fully device-resident — the old overlap
     machinery (host binning of round r-1's panels while round r runs)
@@ -862,7 +861,7 @@ def _run_blocks_sharded(load_matrix: np.ndarray, lidx: np.ndarray,
     rounds = nb // d
     npad = nb * block
     fn = _sharded_agg_fn(d, version, dt_hours, slo_limit, slo_mode,
-                         backend, interpret, block,
+                         backend, block,
                          faulted=fault is not None)
     matrix_dev = jnp.asarray(load_matrix)
     agg_width = AGG_KDIM if backend == "pallas" else AGG_DIM
@@ -884,7 +883,7 @@ def _run_blocks_sharded(load_matrix: np.ndarray, lidx: np.ndarray,
     # every call must sit inside enable_x64 or jit re-traces a truncated
     # f32 variant; the Pallas round jit is pure f32 and stays outside
     ctx = (contextlib.nullcontext() if backend == "pallas"
-           else enable_x64())
+           else jax.enable_x64(True))
     with ctx:
         for r in range(rounds):
             cache0 = obs.jit_cache_size(fn) if obs.enabled() else 0
@@ -908,7 +907,7 @@ def _run_blocks_sharded(load_matrix: np.ndarray, lidx: np.ndarray,
 def _run_blocks_single(load_matrix: np.ndarray, lidx: np.ndarray,
                        params: np.ndarray, block_policy: np.ndarray,
                        version: int, dt_hours: float, slo_limit: float,
-                       slo_mode: int, backend: str, interpret: bool,
+                       slo_mode: int, backend: str,
                        fault=None):
     """The one-device streaming engine: every block runs fully
     device-resident — no latency panel ever crosses to the host and the
@@ -942,7 +941,7 @@ def _run_blocks_single(load_matrix: np.ndarray, lidx: np.ndarray,
                           policy=int(block_policy[b]),
                           backend="pallas") as sp:
                 carry_acc, agg_acc = _agg_block_step_pallas(
-                    version, dt_hours, slo_limit, slo_mode, interpret,
+                    version, dt_hours, slo_limit, slo_mode,
                     matrix_t, jnp.asarray(lidx[b]),
                     jnp.asarray(params[b]),
                     jnp.asarray(block_policy[b]), carry_acc, agg_acc,
@@ -963,7 +962,7 @@ def _run_blocks_single(load_matrix: np.ndarray, lidx: np.ndarray,
     else:
         fargs = lambda b: ()  # noqa: E731
     agg_acc = jnp.zeros((npad, AGG_DIM), jnp.float32)
-    with enable_x64():      # the block step traces f64 — see its docstring
+    with jax.enable_x64(True):   # the block step traces f64 (docstring)
         for b in range(nb):
             cache0 = (obs.jit_cache_size(_agg_block_step_xla)
                       if obs.enabled() else 0)
@@ -1064,7 +1063,6 @@ def _grid_agg_dispatch(load_matrix: np.ndarray, load_index: np.ndarray,
             fault_k)
         return carry_u[inv], agg_u[inv]
     backend = "pallas" if ops.pallas_enabled() else "xla"
-    interpret = ops.interpret_enabled()
     # the Pallas path still stages per-block [T, B] column panels (one
     # for loads, +2 for a fault grid's caps/fmask); the device-resident
     # XLA path stages none — derive the auto-block from what the chosen
@@ -1139,14 +1137,14 @@ def _grid_agg_dispatch(load_matrix: np.ndarray, load_index: np.ndarray,
         carry, agg = _run_blocks_sharded(
             np.asarray(load_matrix), lidx, params_b, block_policy, d,
             version, float(dt_hours), float(slo_limit), int(slo_mode),
-            backend, interpret, fault=block_fault)
+            backend, fault=block_fault)
         carry = carry[:nb * block]
         agg = agg[:nb * block]
     else:
         carry, agg = _run_blocks_single(
             np.asarray(load_matrix), lidx, params_b, block_policy,
             version, float(dt_hours), float(slo_limit), int(slo_mode),
-            backend, interpret, fault=block_fault)
+            backend, fault=block_fault)
 
     # scatter block results back to grid order through the position map
     flat_pos = positions.reshape(-1)

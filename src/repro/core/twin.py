@@ -639,7 +639,7 @@ def device_latency_histogram(latency, weights):
     bit pattern (``_hist_bucket``), then ONE flat ``segment_sum`` over
     (scenario * AGG_HIST_BINS + bucket) ids in f64.
 
-    MUST be traced under ``jax.experimental.enable_x64()`` — outside it
+    MUST be traced under ``jax.enable_x64(True)`` — outside it
     the f64 cast silently truncates to f32 and bit-parity with
     ``np_latency_histogram`` is lost. The f64 adds are exact at the
     magnitudes year grids produce (bucket sums need ~35-51 bits < 53),
@@ -755,7 +755,7 @@ def finalize_aggregate(packed: jnp.ndarray) -> jnp.ndarray:
     """[..., AGG_KDIM] kernel rows -> [..., AGG_DIM] public rows: each
     bucket's (sum, comp, comp2) triple recombined in f64 then cast f32.
 
-    MUST be traced under ``jax.experimental.enable_x64()`` (like
+    MUST be traced under ``jax.enable_x64(True)`` (like
     ``device_latency_histogram``): the f64 recombination of the
     twice-compensated triple is exact, so the result equals numpy's f64
     ``np.bincount`` rounded once — an f32-only recombination double-
@@ -776,8 +776,7 @@ def finalize_aggregate_x64(packed: jnp.ndarray) -> jnp.ndarray:
     ``enable_x64`` around a module-level jit, so the compiled cache only
     ever holds the f64-correct variant (calling the same jit outside the
     ctx would silently re-trace a truncated-f32 one)."""
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         return _finalize_aggregate_jit(packed)
 
 

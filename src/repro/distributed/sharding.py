@@ -73,41 +73,6 @@ def resolve_mesh_axis(devices, dim: int, where: str,
     return devices
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
-              axis_names=None):
-    """Version-compat ``shard_map``: the top-level ``jax.shard_map`` API
-    (jax >= 0.6, with ``check_vma`` / ``axis_names``) when available, else
-    ``jax.experimental.shard_map.shard_map`` with the kwargs translated
-    (``check_vma`` -> ``check_rep``; ``axis_names`` -> the complement
-    ``auto`` set). Use this everywhere instead of either spelling.
-
-    Body contract: keep everything in-graph. Host callbacks
-    (``io_callback`` / ``pure_callback``) inside a sharded body serialize
-    multi-device dispatch and can deadlock it outright — the aggregate
-    grid's round step (``core.simulate._sharded_agg_fn``) was once built
-    AROUND that constraint, draining per-round latency panels to the host
-    for binning; its histogram now accumulates in-body on device, so the
-    constraint costs nothing. Device-wide reductions (f64 ``segment_sum``
-    included) are fine in-body; only sharded-in/sharded-out data flow
-    crosses the boundary."""
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {}
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-
-
 def mesh_shape_dict(mesh: Mesh) -> Dict[str, int]:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
 

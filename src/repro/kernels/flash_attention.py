@@ -68,7 +68,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool) -> jnp.ndarray:
     """q: [b, sq, h, d]; k/v: [b, sk, kh, d]. Returns [b, sq, h, dv]."""
     b, sq, h, d = q.shape
     _, sk, kh, dv = v.shape

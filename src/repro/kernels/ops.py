@@ -1,9 +1,11 @@
 """jit'd dispatch layer between Pallas kernels and jnp references.
 
 ``use_pallas(True)`` flips attention / rwkv6 / ssm / policy-grid hot
-paths to their Pallas implementations (TPU target; ``interpret=True`` on
-CPU for tests). The default is the XLA reference path so the 512-device
-dry-run lowers on the CPU container. Model code imports ONLY from this
+paths to their Pallas implementations. The default is the XLA reference
+path so the 512-device dry-run lowers on the CPU container. Whether a
+Pallas kernel compiles with Mosaic or runs in the interpreter is not an
+option: ``interpret_mode()`` derives it from the platform (interpreter
+on the CPU, Mosaic on a TPU). Model code imports ONLY from this
 module; the what-if grid backend (``core.simulate._grid_scan``) selects
 through ``policy_scan`` here.
 """
@@ -12,22 +14,21 @@ from __future__ import annotations
 import contextlib
 import threading
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
 
 _state = threading.local()
 _state.pallas = False
-_state.interpret = True
 # XLA-path attention chunking: 0 = exact quadratic einsum; >0 = flash-style
 # blocked online softmax with this chunk (dry-run memfit mode; ref of the
 # Pallas kernel). Applies when seq_len is a multiple of the chunk.
 _state.attn_chunk = 0
 
 
-def use_pallas(enable: bool = True, interpret: bool = True):
+def use_pallas(enable: bool = True):
     _state.pallas = enable
-    _state.interpret = interpret
 
 
 def set_attn_chunk(chunk: int):
@@ -42,20 +43,21 @@ def pallas_enabled() -> bool:
     return getattr(_state, "pallas", False)
 
 
-def interpret_enabled() -> bool:
-    """Whether Pallas kernels run in interpret mode (CPU) — the public
-    accessor callers outside this module must use."""
-    return getattr(_state, "interpret", True)
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter: exactly when the
+    default backend is the CPU. On a TPU every kernel compiles with
+    Mosaic."""
+    return jax.default_backend() == "cpu"
 
 
 @contextlib.contextmanager
-def pallas_mode(enable: bool = True, interpret: bool = True):
-    prev = (getattr(_state, "pallas", False), getattr(_state, "interpret", True))
-    use_pallas(enable, interpret)
+def pallas_mode(enable: bool = True):
+    prev = getattr(_state, "pallas", False)
+    use_pallas(enable)
     try:
         yield
     finally:
-        use_pallas(*prev)
+        use_pallas(prev)
 
 
 def sdpa(q, k, v, *, causal=True, scale=None, logit_cap=0.0, kv_len=None):
@@ -63,7 +65,7 @@ def sdpa(q, k, v, *, causal=True, scale=None, logit_cap=0.0, kv_len=None):
         from repro.kernels import flash_attention
         return flash_attention.flash_attention(
             q, k, v, causal=causal, scale=scale,
-            interpret=getattr(_state, "interpret", True))
+            interpret=interpret_mode())
     chunk = get_attn_chunk()
     if (chunk > 0 and kv_len is None and logit_cap == 0.0
             and q.shape[1] > chunk and q.shape[1] % chunk == 0
@@ -78,7 +80,7 @@ def rwkv6_scan(r, k, v, w, u, state=None):
     if pallas_enabled():
         from repro.kernels import rwkv6_kernel
         return rwkv6_kernel.rwkv6(r, k, v, w, u, state,
-                                  interpret=getattr(_state, "interpret", True))
+                                  interpret=interpret_mode())
     return ref.rwkv6_scan(r, k, v, w, u, state)
 
 
@@ -86,7 +88,7 @@ def ssm_scan(x, dt, A, B, C, D, state=None):
     if pallas_enabled():
         from repro.kernels import ssm_scan as ssm_kernel
         return ssm_kernel.ssm(x, dt, A, B, C, D, state,
-                              interpret=getattr(_state, "interpret", True))
+                              interpret=interpret_mode())
     return ref.ssm_scan(x, dt, A, B, C, D, state)
 
 
@@ -144,16 +146,13 @@ def policy_scan(loads, params, onehot=None, dt_hours=1.0, *,
         if onehot is None:
             # the kernel's branch selector is the mask form; a traced
             # uniform index lowers to its one-hot row broadcast over lanes
-            import jax
-
             from repro.core.twin import num_policies
             onehot = jnp.broadcast_to(
                 jax.nn.one_hot(policy_index, num_policies(),
                                dtype=jnp.float32),
                 (loads.shape[0], num_policies()))
-        return policy_kernel.policy_grid_scan(
-            loads, params, onehot, dt_hours,
-            interpret=getattr(_state, "interpret", True))
+        return policy_kernel.policy_grid_scan(loads, params, onehot,
+                                              dt_hours)
     if differentiable or surrogate:
         try:
             dt_static = float(dt_hours)   # tracers raise TypeError
@@ -233,8 +232,7 @@ def policy_scan_agg(loads, params, onehot, dt_hours=1.0, *,
         from repro.kernels import policy_scan as policy_kernel
         return policy_kernel.policy_grid_agg(
             loads, params, onehot, dt_hours, slo_limit=float(slo_limit),
-            slo_mode=int(slo_mode), caps=caps, fmask=fmask,
-            interpret=getattr(_state, "interpret", True))
+            slo_mode=int(slo_mode), caps=caps, fmask=fmask)
     return ref.policy_grid_agg(loads, params, onehot, dt_hours,
                                slo_limit=float(slo_limit),
                                slo_mode=int(slo_mode), caps=caps,

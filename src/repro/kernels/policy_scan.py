@@ -18,10 +18,15 @@ path (``core.simulate._grid_scan``) runs it as vmap-of-scan with a
   across time chunks, so HBM sees each load bin exactly once and the
   carry never round-trips (the XLA scan materialises it per step).
 
-On CPU this runs with ``interpret=True`` (tests, this container); the
-grid/BlockSpec structure is the TPU layout. ``chunk`` bounds VMEM: a
-(chunk x LANES) f32 block per operand/output. The (lanes, chunk) tile
-is derived from the device's VMEM budget by ``tile_plan`` (lanes pinned
+The public entry points compile with Mosaic on a TPU and run the Pallas
+interpreter on the CPU (``kernels.ops.interpret_mode``); the
+grid/BlockSpec structure is the TPU layout. Each bin's row is read from
+the operand ref inside the bin loop (``ref[pl.ds(t, 1), :]``) and the
+series kernel writes each bin's outputs straight into its output refs:
+Mosaic has no lowering for a dynamic slice of a loaded value.
+``chunk`` bounds VMEM: a (chunk x LANES) f32 block per operand/output.
+The (lanes, chunk) tile is derived from the device's VMEM budget by
+``tile_plan`` (lanes pinned
 to the 128-wide VPU lane axis, chunk the largest divisor of the horizon
 whose double-buffered operand blocks plus the per-lane resident state
 fit the budget) rather than hard-coded year-shaped constants; the plan
@@ -114,31 +119,21 @@ def _policy_scan_kernel(loads_ref, params_ref, onehot_ref,
     def _init():
         carry_ref[...] = jnp.zeros((lanes, carry_dim), jnp.float32)
 
-    loads = loads_ref[...]            # [chunk, LANES]
     params = params_ref[...]          # [LANES, PARAM_DIM]
     onehot = onehot_ref[...]          # [LANES, P]
     dt_f = jnp.float32(dt)
 
-    def bin_step(t, state):
-        carry, proc, queue, lat, cost, drop = state
-        carry, (p, q, l, co, dr) = step(carry, loads[t], params, onehot,
-                                        dt_f)
-        upd = functools.partial(jax.lax.dynamic_update_slice_in_dim,
-                                start_index=t, axis=0)
-        return (carry, upd(proc, p[None]), upd(queue, q[None]),
-                upd(lat, l[None]), upd(cost, co[None]),
-                upd(drop, dr[None]))
+    def bin_step(t, carry):
+        row = pl.ds(t, 1)
+        carry, outs = step(carry, loads_ref[row, :][0], params, onehot,
+                           dt_f)
+        for ref, o in zip((proc_ref, queue_ref, lat_ref, cost_ref,
+                           drop_ref), outs):
+            ref[row, :] = o[None]
+        return carry
 
-    zeros = lambda: jnp.zeros((chunk, lanes), jnp.float32)  # noqa: E731
-    carry, proc, queue, lat, cost, drop = jax.lax.fori_loop(
-        0, chunk, bin_step,
-        (carry_ref[...], zeros(), zeros(), zeros(), zeros(), zeros()))
+    carry = jax.lax.fori_loop(0, chunk, bin_step, carry_ref[...])
     carry_ref[...] = carry
-    proc_ref[...] = proc
-    queue_ref[...] = queue
-    lat_ref[...] = lat
-    cost_ref[...] = cost
-    drop_ref[...] = drop
 
     @pl.when(c == num_chunks - 1)
     def _fin():
@@ -208,15 +203,15 @@ def _policy_agg_kernel(loads_ref, params_ref, onehot_ref,
         carry_ref[...] = jnp.zeros((lanes, carry_dim), jnp.float32)
         agg_ref[...] = jnp.zeros((lanes, agg_dim), jnp.float32)
 
-    loads = loads_ref[...]            # [chunk, LANES]
     params = params_ref[...]          # [LANES, PARAM_DIM]
     onehot = onehot_ref[...]          # [LANES, P]
     dt_f = jnp.float32(dt)
 
     def bin_step(t, state):
         carry, agg = state
-        carry, outs = step(carry, loads[t], params, onehot, dt_f)
-        agg = update(agg, loads[t], outs, slo_limit, slo_mode)
+        arrive = loads_ref[pl.ds(t, 1), :][0]
+        carry, outs = step(carry, arrive, params, onehot, dt_f)
+        agg = update(agg, arrive, outs, slo_limit, slo_mode)
         return carry, agg
 
     carry, agg = jax.lax.fori_loop(0, chunk, bin_step,
@@ -253,18 +248,18 @@ def _policy_agg_fault_kernel(loads_ref, caps_ref, fmask_ref, params_ref,
         carry_ref[...] = jnp.zeros((lanes, carry_dim + 1), jnp.float32)
         agg_ref[...] = jnp.zeros((lanes, agg_dim), jnp.float32)
 
-    loads = loads_ref[...]            # [chunk, LANES]
-    caps = caps_ref[...]              # [chunk, LANES]
-    fmask = fmask_ref[...]            # [chunk, LANES]
     params = params_ref[...]          # [LANES, PARAM_DIM]
     onehot = onehot_ref[...]          # [LANES, P]
     dt_f = jnp.float32(dt)
 
     def bin_step(t, state):
         carry, fq, agg = state
-        (carry, fq), outs = step((carry, fq), loads[t], caps[t], params,
-                                 onehot, dt_f)
-        agg = update(agg, loads[t], outs, slo_limit, slo_mode, fmask[t])
+        row = pl.ds(t, 1)
+        arrive = loads_ref[row, :][0]
+        (carry, fq), outs = step((carry, fq), arrive, caps_ref[row, :][0],
+                                 params, onehot, dt_f)
+        agg = update(agg, arrive, outs, slo_limit, slo_mode,
+                     fmask_ref[row, :][0])
         return carry, fq, agg
 
     cf = carry_ref[...]
@@ -427,9 +422,8 @@ def _stage_aux(aux, aux_t, t_bins: int, n: int, npad: int, what: str):
 def policy_grid_agg(loads: jnp.ndarray | None, params: jnp.ndarray,
                     onehot: jnp.ndarray, dt_hours: float = 1.0, *,
                     slo_limit: float = float("inf"), slo_mode: int = 0,
-                    lanes: int = None, chunk: int = None,
-                    interpret: bool = True, loads_t=None, caps=None,
-                    fmask=None, caps_t=None, fmask_t=None,
+                    lanes: int = None, chunk: int = None, loads_t=None,
+                    caps=None, fmask=None, caps_t=None, fmask_t=None,
                     finalize: bool = True):
     """Fused streaming-aggregate grid scan; semantics of
     ``ref.policy_grid_agg``. Same padding/transposition contract as
@@ -448,6 +442,7 @@ def policy_grid_agg(loads: jnp.ndarray | None, params: jnp.ndarray,
     (carry_end [N, CARRY_DIM], agg [N, AGG_DIM]).
     """
     from repro.core.twin import finalize_aggregate_x64, registry_version
+    from repro.kernels.ops import interpret_mode
     n, t_bins, npad, lanes, chunk, loads_t = _stage_operands(
         loads, loads_t, lanes, chunk, params.shape[1])
     pad = lambda a: jnp.zeros((npad, a.shape[1]), jnp.float32).at[:n].set(  # noqa: E731
@@ -461,13 +456,13 @@ def policy_grid_agg(loads: jnp.ndarray | None, params: jnp.ndarray,
             loads_t, caps_t, fmask_t, pad(params), pad(onehot),
             dt_hours=float(dt_hours), slo_limit=float(slo_limit),
             slo_mode=int(slo_mode), version=registry_version(),
-            lanes=lanes, chunk=chunk, interpret=interpret)
+            lanes=lanes, chunk=chunk, interpret=interpret_mode())
     else:
         carry_end, agg = _policy_agg(
             loads_t, pad(params), pad(onehot), dt_hours=float(dt_hours),
             slo_limit=float(slo_limit), slo_mode=int(slo_mode),
             version=registry_version(), lanes=lanes, chunk=chunk,
-            interpret=interpret)
+            interpret=interpret_mode())
     if finalize:
         agg = finalize_aggregate_x64(agg)
     return carry_end[:n], agg[:n]
@@ -475,8 +470,7 @@ def policy_grid_agg(loads: jnp.ndarray | None, params: jnp.ndarray,
 
 def policy_grid_scan(loads: jnp.ndarray | None, params: jnp.ndarray,
                      onehot: jnp.ndarray, dt_hours: float = 1.0, *,
-                     lanes: int = None, chunk: int = None,
-                     interpret: bool = True, loads_t=None):
+                     lanes: int = None, chunk: int = None, loads_t=None):
     """Fused scenario-grid scan; same contract as ``ref.policy_grid_scan``.
 
     loads [N, T]; params [N, PARAM_DIM]; onehot [N, P]. The scenario axis
@@ -489,6 +483,7 @@ def policy_grid_scan(loads: jnp.ndarray | None, params: jnp.ndarray,
     with each series [N, T].
     """
     from repro.core.twin import registry_version
+    from repro.kernels.ops import interpret_mode
     n, t_bins, npad, lanes, chunk, loads_t = _stage_operands(
         loads, loads_t, lanes, chunk, params.shape[1])
     pad = lambda a: jnp.zeros((npad, a.shape[1]), jnp.float32).at[:n].set(  # noqa: E731
@@ -496,6 +491,6 @@ def policy_grid_scan(loads: jnp.ndarray | None, params: jnp.ndarray,
     proc, queue, lat, cost, drop, carry_end = _policy_scan(
         loads_t, pad(params), pad(onehot), dt_hours=float(dt_hours),
         version=registry_version(), lanes=lanes, chunk=chunk,
-        interpret=interpret)
+        interpret=interpret_mode())
     series = tuple(o[:, :n].T for o in (proc, queue, lat, cost, drop))
     return carry_end[:n], series

@@ -82,7 +82,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rwkv6(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, w: jnp.ndarray,
           u: jnp.ndarray, state: Optional[jnp.ndarray] = None, *,
-          chunk: int = 16, interpret: bool = True
+          interpret: bool, chunk: int = 16
           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """r,k,v,w: [b, s, h, n]; u: [h, n]; state: [b, h, n, n] or None."""
     b, s, h, n = r.shape

@@ -62,7 +62,7 @@ def _ssm_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, D_ref, s0_ref,
 @functools.partial(jax.jit, static_argnames=("chunk", "d_block", "interpret"))
 def ssm(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray,
         C: jnp.ndarray, D: jnp.ndarray, state: Optional[jnp.ndarray] = None,
-        *, chunk: int = 64, d_block: int = 128, interpret: bool = True
+        *, interpret: bool, chunk: int = 64, d_block: int = 128
         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x, dt: [b, s, di]; A: [di, n]; B, C: [b, s, n]; D: [di]."""
     b, s, di = x.shape

@@ -248,7 +248,6 @@ def _sharded_search_fn(devices: int, steps: int, n_scen: int, n_fut: int,
     the mesh only divides wall clock and per-device live memory."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.distributed.sharding import shard_map
     mesh = Mesh(np.asarray(jax.devices()[:devices]), ("restart",))
     shard, rep = P("restart"), P()
 
@@ -268,8 +267,8 @@ def _sharded_search_fn(devices: int, steps: int, n_scen: int, n_fut: int,
     # [T, 0] caps placeholder — one body signature serves both modes
     in_specs = (shard,) + (rep,) * 10 + (shard,) + (rep,) * 6
     out_specs = (shard, shard, shard, shard, shard, P(None, "restart"))
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 @dataclass
@@ -661,7 +660,7 @@ def search(space_or_base: Union[SearchSpace, Twin],
     vectorized materialized objective — the choice is a compile-time
     static made from global sizes, invisible to results.
     ``devices=D`` additionally shards the restart axis over a D-device
-    mesh through the ``distributed/sharding.py`` shim — restarts are
+    mesh through ``jax.shard_map`` — restarts are
     independent, so results are **bit-identical** to ``devices=None``
     and the mesh only divides wall clock and per-device memory. On a
     multi-core CPU host export

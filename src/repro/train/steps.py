@@ -191,7 +191,6 @@ def _pod_compressed_grads(compute_grads, params: Dict, batch: Dict,
         else:
             batch_in[k] = P()
     # 'pod' is the only manual axis; in-pod data/model stay under GSPMD
-    from repro.distributed.sharding import shard_map
-    return shard_map(body, mesh=mesh, in_specs=(param_in, batch_in),
-                     out_specs=(param_in, P()), check_vma=False,
-                     axis_names={"pod"})(params, batch)
+    return jax.shard_map(body, mesh=mesh, in_specs=(param_in, batch_in),
+                         out_specs=(param_in, P()), check_vma=False,
+                         axis_names={"pod"})(params, batch)

@@ -187,8 +187,7 @@ def test_aggregate_pytree_has_no_series_axis():
     assert_o_n(ref.policy_grid_agg(loads, jnp.asarray(params),
                                    jnp.asarray(policy_onehot(idx)), 1.0))
     assert_o_n(policy_grid_agg(loads, jnp.asarray(params),
-                               jnp.asarray(policy_onehot(idx)), 1.0,
-                               interpret=True))
+                               jnp.asarray(policy_onehot(idx)), 1.0))
     carry_end, agg = _grid_agg_dispatch(matrix, index, params, idx, 1.0,
                                         float("inf"), 0, scenario_block=5)
     assert carry_end.shape == (n, CARRY_DIM) and agg.shape == (n, AGG_DIM)

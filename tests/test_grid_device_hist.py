@@ -27,7 +27,6 @@ import pytest
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
-from jax.experimental import enable_x64  # noqa: E402
 
 from repro import faults  # noqa: E402
 from repro.core import simulate  # noqa: E402
@@ -111,7 +110,7 @@ def test_device_hist_bit_identical_pallas_all_policies():
     n = 10
     _, matrix, index, params, idx = _grid_arrays(n)
     oracle = _oracle_hist(matrix, index, params, idx)
-    with ops.pallas_mode(interpret=True):
+    with ops.pallas_mode():
         for block in (None, 4):
             _, agg = _grid_agg_dispatch(matrix, index, params, idx, 1.0,
                                         float("inf"), 0, block)
@@ -128,7 +127,7 @@ def test_device_hist_bit_identical_devices_4():
                                 float("inf"), 0, 4, devices=4)
     np.testing.assert_array_equal(
         agg[:, AGG_SCALARS:].astype(np.float32), oracle)
-    with ops.pallas_mode(interpret=True):
+    with ops.pallas_mode():
         _, agg_p = _grid_agg_dispatch(matrix, index, params, idx, 1.0,
                                       float("inf"), 0, 4, devices=4)
     np.testing.assert_array_equal(
@@ -160,7 +159,7 @@ def test_device_hist_bit_identical_chaos_grid():
                                     fault=fault)
         np.testing.assert_array_equal(
             agg[:, AGG_SCALARS:].astype(np.float32), oracle)
-    with ops.pallas_mode(interpret=True):
+    with ops.pallas_mode():
         _, agg_p = _grid_agg_dispatch(fg.load_matrix, fg.load_index,
                                       params_f, idx_f, 1.0, float("inf"),
                                       0, 4, fault=fault)
@@ -195,7 +194,7 @@ def test_no_bt_intermediate_in_xla_driver():
     matrix = jnp.ones((k, t_bins), jnp.float32)
     lidx = jnp.zeros((b,), jnp.int32)
     params = jnp.ones((b, 6), jnp.float32)
-    with enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(
             lambda m, li, p: _agg_scan_uniform(m, li, p, 0, 1.0,
                                                float("inf"), 0))(
@@ -218,8 +217,8 @@ def test_sharded_round_step_outputs_are_o_n():
     p_block = np.tile(ALL_POLICY_TWINS[0].padded_params(),
                       (block, 1)).astype(np.float32)
     fn = _sharded_agg_fn(1, registry_version(), 1.0, float("inf"), 0,
-                         "xla", True, block)
-    with enable_x64():
+                         "xla", block)
+    with jax.enable_x64(True):
         out = fn(jnp.asarray(matrix), jnp.asarray(index[None]),
                  jnp.asarray(p_block[None]), jnp.asarray([0], np.int32))
     shapes = [tuple(leaf.shape) for leaf in jax.tree_util.tree_leaves(out)]

@@ -159,18 +159,16 @@ def test_agg_block_plan_empty_grid():
 # ---------------------------------------------------------------------------
 
 def test_sharded_round_step_matches_uniform_scan_one_device():
-    from jax.experimental import enable_x64
-
     block = 8
     _, matrix, index, params, _ = _grid_arrays(block)
     lidx = index.astype(np.int32)
     p_block = np.tile(ALL_POLICY_TWINS[0].padded_params(),
                       (block, 1)).astype(np.float32)
     fn = _sharded_agg_fn(1, registry_version(), 1.0, float("inf"), 0,
-                         "xla", True, block)
+                         "xla", block)
     # the round step keeps the histogram in-body and traces f64; every
     # call site enters under enable_x64 (see _run_blocks_sharded)
-    with enable_x64():
+    with jax.enable_x64(True):
         carry, agg = fn(jnp.asarray(matrix), jnp.asarray(lidx[None]),
                         jnp.asarray(p_block[None]),
                         jnp.asarray([0], np.int32))
@@ -317,21 +315,20 @@ def test_kernel_loads_t_staging_matches_loads():
     _, matrix, index, params, idx = _grid_arrays(n, t_bins=97)
     loads = matrix[index]
     onehot = policy_onehot(idx)
-    a = policy_grid_agg(loads, params, onehot, 1.0, interpret=True)
-    b = policy_grid_agg(None, params, onehot, 1.0, interpret=True,
+    a = policy_grid_agg(loads, params, onehot, 1.0)
+    b = policy_grid_agg(None, params, onehot, 1.0,
                         loads_t=np.ascontiguousarray(loads.T))
     for x, y in zip(jax.tree_util.tree_leaves(a),
                     jax.tree_util.tree_leaves(b)):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-    sa = policy_grid_scan(loads, params, onehot, 1.0, interpret=True)
-    sb = policy_grid_scan(None, params, onehot, 1.0, interpret=True,
+    sa = policy_grid_scan(loads, params, onehot, 1.0)
+    sb = policy_grid_scan(None, params, onehot, 1.0,
                           loads_t=np.ascontiguousarray(loads.T))
     for x, y in zip(jax.tree_util.tree_leaves(sa),
                     jax.tree_util.tree_leaves(sb)):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
     for f in (policy_grid_scan, policy_grid_agg):
         with pytest.raises(ValueError, match="exactly one"):
-            f(None, params, onehot, 1.0, interpret=True)
+            f(None, params, onehot, 1.0)
         with pytest.raises(ValueError, match="exactly one"):
-            f(loads, params, onehot, 1.0, interpret=True,
-              loads_t=loads.T)
+            f(loads, params, onehot, 1.0, loads_t=loads.T)

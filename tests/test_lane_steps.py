@@ -25,7 +25,7 @@ LANES = 4
 finite = dict(allow_nan=False, allow_infinity=False, width=32)
 carry_vals = st.floats(0.0, 1e5, **finite)
 arrive_vals = st.floats(0.0, 1e5, **finite)
-param_vals = st.floats(1e-3, 1e3, **finite)
+param_vals = st.floats(float(np.float32(1e-3)), 1e3, **finite)
 dts = st.sampled_from([1.0, 0.25, 1.0 / 60.0, 1.0 / 3600.0])
 
 

@@ -20,7 +20,6 @@ import sys
 sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.distributed.sharding import shard_map
 from repro.optim.compression import quantize_int8
 
 mesh = jax.make_mesh((8,), ("pod",))
@@ -40,10 +39,10 @@ def exact_psum(g):
 
 rng = np.random.default_rng(0)
 g = jnp.asarray(rng.normal(size=(8, 256)).astype(np.float32))
-run_c = jax.jit(shard_map(compressed_psum, mesh=mesh,
-                          in_specs=P("pod"), out_specs=P("pod")))
-run_e = jax.jit(shard_map(exact_psum, mesh=mesh,
-                          in_specs=P("pod"), out_specs=P("pod")))
+run_c = jax.jit(jax.shard_map(compressed_psum, mesh=mesh,
+                              in_specs=P("pod"), out_specs=P("pod")))
+run_e = jax.jit(jax.shard_map(exact_psum, mesh=mesh,
+                              in_specs=P("pod"), out_specs=P("pod")))
 got, want = np.asarray(run_c(g)), np.asarray(run_e(g))
 # error bounded by one int8 step of the max per-block scale
 bound = np.abs(g).max() / 127.0 + 1e-6

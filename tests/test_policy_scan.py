@@ -92,7 +92,7 @@ def test_pallas_kernel_matches_xla_switch_64():
     q_end, outs_x = _xla(loads, params, idx)
     carry_end, outs_p = policy_grid_scan(
         jnp.asarray(loads), jnp.asarray(params),
-        jnp.asarray(policy_onehot(idx)), 1.0, interpret=True)
+        jnp.asarray(policy_onehot(idx)), 1.0)
     _assert_series_close(outs_p, outs_x)
     np.testing.assert_allclose(np.asarray(carry_end[:, 0]),
                                np.asarray(q_end), rtol=1e-5)
@@ -105,8 +105,7 @@ def test_pallas_kernel_scenario_padding_and_lane_blocking():
     for lanes in (8, 128):
         carry_end, outs_p = policy_grid_scan(
             jnp.asarray(loads), jnp.asarray(params),
-            jnp.asarray(policy_onehot(idx)), 1.0, lanes=lanes,
-            interpret=True)
+            jnp.asarray(policy_onehot(idx)), 1.0, lanes=lanes)
         _assert_series_close(outs_p, outs_x)
         np.testing.assert_allclose(np.asarray(carry_end[:, 0]),
                                    np.asarray(q_end), rtol=1e-5)
@@ -123,7 +122,7 @@ def test_pallas_kernel_short_horizon_subhour_bins():
     q_end, outs_x = _xla(loads, params, idx, dt=1.0 / 60.0)
     carry_end, outs_p = policy_grid_scan(
         jnp.asarray(loads), jnp.asarray(params),
-        jnp.asarray(policy_onehot(idx)), 1.0 / 60.0, interpret=True)
+        jnp.asarray(policy_onehot(idx)), 1.0 / 60.0)
     _assert_series_close(outs_p, outs_x)
 
 
