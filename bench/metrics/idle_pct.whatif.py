@@ -1,0 +1,7 @@
+"""Device: share of the traced window in which a chip ran no operation,
+averaged over the cell's chips (traced whatif requests)."""
+from bench import layers
+
+
+def read(ctx):
+    return layers.idle_pct(ctx, "whatif")
