@@ -839,7 +839,27 @@ def _sharded_agg_fn(devices: int, version: int, dt_hours: float,
         in_specs=in_specs,
         out_specs=out_specs,
         check_vma=False)
-    return jax.jit(sharded)
+
+    def _mesh_agg_round(*args):
+        # a named function, so the profiler names the round's program
+        # ``jit__mesh_agg_round`` whatever the body is called
+        return sharded(*args)
+
+    return jax.jit(_mesh_agg_round)
+
+
+def _to_device(*arrays, wait: bool = False):
+    """``jnp.asarray`` of each host array, counting the bytes copied in
+    the ``grid.h2d_bytes`` counter. ``wait`` (the ``grid.upload`` sites,
+    where no device work is queued yet, so waiting serialises nothing)
+    blocks on the copies when telemetry is on, so the enclosing span
+    times the transfer."""
+    out = [jnp.asarray(a) for a in arrays]
+    if obs.enabled():
+        obs.count("grid.h2d_bytes", sum(a.nbytes for a in out))
+        if wait:
+            jax.block_until_ready(out)
+    return out
 
 
 def _run_blocks_sharded(load_matrix: np.ndarray, lidx: np.ndarray,
@@ -863,21 +883,14 @@ def _run_blocks_sharded(load_matrix: np.ndarray, lidx: np.ndarray,
     fn = _sharded_agg_fn(d, version, dt_hours, slo_limit, slo_mode,
                          backend, block,
                          faulted=fault is not None)
-    matrix_dev = jnp.asarray(load_matrix)
+    with obs.span("grid.upload"):
+        shared = _to_device(load_matrix, *(fault[:2] if fault is not None
+                                           else ()), wait=True)
     agg_width = AGG_KDIM if backend == "pallas" else AGG_DIM
     carry_out = np.empty((npad, CARRY_DIM), np.float32)
     agg_out = np.empty((npad, agg_width), np.float32)
-
-    def rnd(a, r):
-        return jnp.asarray(a[r * d:(r + 1) * d])
-
-    if fault is not None:
-        cap_dev = jnp.asarray(fault[0])
-        fmask_dev = jnp.asarray(fault[1])
-        fidx_blocks = fault[2]
-        fargs = lambda r: (cap_dev, fmask_dev, rnd(fidx_blocks, r))  # noqa: E731
-    else:
-        fargs = lambda r: ()  # noqa: E731
+    per_round = (lidx, params, block_policy) + (
+        (fault[2],) if fault is not None else ())
 
     # the XLA round jit traces f64 (in-graph histogram segment_sum) —
     # every call must sit inside enable_x64 or jit re-traces a truncated
@@ -890,15 +903,17 @@ def _run_blocks_sharded(load_matrix: np.ndarray, lidx: np.ndarray,
             with obs.span("grid.round", round=r, devices=d, block=block,
                           backend=backend,
                           scenarios=d * block) as sp:
-                carry, agg = fn(matrix_dev, rnd(lidx, r), rnd(params, r),
-                                rnd(block_policy, r), *fargs(r))
+                lr, pr, bpr, *fr = _to_device(
+                    *(a[r * d:(r + 1) * d] for a in per_round))
+                carry, agg = fn(shared[0], lr, pr, bpr, *shared[1:], *fr)
                 jax.block_until_ready(agg)
             if obs.enabled():
                 sp.attrs["compiled"] = float(
                     obs.jit_cache_grew(fn, cache0))
             sl = slice(r * d * block, (r + 1) * d * block)
-            carry_out[sl] = np.asarray(carry).reshape(-1, CARRY_DIM)
-            agg_out[sl] = np.asarray(agg).reshape(-1, agg.shape[-1])
+            with obs.span("grid.drain"):
+                carry_out[sl] = np.asarray(carry).reshape(-1, CARRY_DIM)
+                agg_out[sl] = np.asarray(agg).reshape(-1, agg.shape[-1])
     if backend == "pallas":
         agg_out = np.asarray(finalize_aggregate_x64(agg_out))
     return carry_out, agg_out
@@ -919,66 +934,47 @@ def _run_blocks_single(load_matrix: np.ndarray, lidx: np.ndarray,
     fidx [NB, B]) threads a fault grid through every block. Returns host
     (carry [NB*B, CARRY_DIM], agg [NB*B, AGG_DIM]) — Pallas blocks
     accumulate raw AGG_KDIM rows, recombined ONCE at the end of the
-    grid."""
+    grid. With telemetry on, ``grid.upload`` times the copy of the
+    shared matrices, each ``grid.block`` span the host's dispatch of its
+    block (it does not wait for the device: the trace gives a block's
+    device time) and ``grid.drain`` the wait for the results and their
+    copy back."""
     nb, block = lidx.shape
     npad = nb * block
+    pallas = backend == "pallas"
+    step = _agg_block_step_pallas if pallas else _agg_block_step_xla
+    shared = (load_matrix,) + (tuple(fault[:2]) if fault is not None
+                               else ())
+    with obs.span("grid.upload"):
+        # the Pallas kernel reads [T, *] column panels
+        shared = _to_device(*(np.asarray(a).T if pallas else a
+                              for a in shared), wait=True)
+    per_block = (lidx, params, block_policy) + (
+        (fault[2],) if fault is not None else ())
     carry_acc = jnp.zeros((npad, CARRY_DIM), jnp.float32)
-    if backend == "pallas":
-        matrix_t = jnp.asarray(load_matrix.T)
-        if fault is not None:
-            cap_mt = jnp.asarray(np.asarray(fault[0]).T)
-            fmask_mt = jnp.asarray(np.asarray(fault[1]).T)
-            fidx_blocks = fault[2]
-            fargs = lambda b: (cap_mt, fmask_mt,  # noqa: E731
-                               jnp.asarray(fidx_blocks[b]))
-        else:
-            fargs = lambda b: ()  # noqa: E731
-        agg_acc = jnp.zeros((npad, AGG_KDIM), jnp.float32)
+    agg_acc = jnp.zeros((npad, AGG_KDIM if pallas else AGG_DIM),
+                        jnp.float32)
+    # the XLA block step traces f64 (docstring); the Pallas one is f32
+    ctx = contextlib.nullcontext() if pallas else jax.enable_x64(True)
+    with ctx:
         for b in range(nb):
-            cache0 = (obs.jit_cache_size(_agg_block_step_pallas)
-                      if obs.enabled() else 0)
+            cache0 = obs.jit_cache_size(step) if obs.enabled() else 0
             with obs.span("grid.block", block=b, size=block,
                           policy=int(block_policy[b]),
-                          backend="pallas") as sp:
-                carry_acc, agg_acc = _agg_block_step_pallas(
-                    version, dt_hours, slo_limit, slo_mode,
-                    matrix_t, jnp.asarray(lidx[b]),
-                    jnp.asarray(params[b]),
-                    jnp.asarray(block_policy[b]), carry_acc, agg_acc,
-                    b * block, *fargs(b))
+                          backend=backend) as sp:
+                lb, pb, bpb, *fb = _to_device(*(a[b] for a in per_block))
+                carry_acc, agg_acc = step(
+                    version, dt_hours, slo_limit, slo_mode, shared[0], lb,
+                    pb, bpb, carry_acc, agg_acc, b * block, *shared[1:],
+                    *fb)
                 if obs.enabled():
-                    jax.block_until_ready(agg_acc)
                     sp.attrs["compiled"] = float(obs.jit_cache_grew(
-                        _agg_block_step_pallas, cache0))
-        return (np.asarray(carry_acc),
-                np.asarray(finalize_aggregate_x64(agg_acc)))
-    matrix_dev = jnp.asarray(load_matrix)
-    if fault is not None:
-        cap_dev = jnp.asarray(fault[0])
-        fmask_dev = jnp.asarray(fault[1])
-        fidx_blocks = fault[2]
-        fargs = lambda b: (cap_dev, fmask_dev,  # noqa: E731
-                           jnp.asarray(fidx_blocks[b]))
-    else:
-        fargs = lambda b: ()  # noqa: E731
-    agg_acc = jnp.zeros((npad, AGG_DIM), jnp.float32)
-    with jax.enable_x64(True):   # the block step traces f64 (docstring)
-        for b in range(nb):
-            cache0 = (obs.jit_cache_size(_agg_block_step_xla)
-                      if obs.enabled() else 0)
-            with obs.span("grid.block", block=b, size=block,
-                          policy=int(block_policy[b]),
-                          backend="xla") as sp:
-                carry_acc, agg_acc = _agg_block_step_xla(
-                    version, dt_hours, slo_limit, slo_mode, matrix_dev,
-                    jnp.asarray(lidx[b]), jnp.asarray(params[b]),
-                    jnp.asarray(block_policy[b]), carry_acc, agg_acc,
-                    b * block, *fargs(b))
-                if obs.enabled():
-                    jax.block_until_ready(agg_acc)
-                    sp.attrs["compiled"] = float(obs.jit_cache_grew(
-                        _agg_block_step_xla, cache0))
-        return np.asarray(carry_acc), np.asarray(agg_acc)
+                        step, cache0))
+        with obs.span("grid.drain"):
+            if pallas:
+                return (np.asarray(carry_acc),
+                        np.asarray(finalize_aggregate_x64(agg_acc)))
+            return np.asarray(carry_acc), np.asarray(agg_acc)
 
 
 def _dedup_rows(load_index: np.ndarray, params: np.ndarray,
@@ -1044,24 +1040,39 @@ def _grid_agg_dispatch(load_matrix: np.ndarray, load_index: np.ndarray,
     exact, because scenarios are independent and deterministic. All
     paths return the same host numpy (carry_end [N, CARRY_DIM], agg
     [N, AGG_DIM]), bit-identical to one another."""
+    n = len(load_index)
+    with obs.span("grid.dedup"):
+        dd = _dedup_rows(load_index, params, policy_idx, fault)
+        if dd is not None:
+            keep, inv, fidx_canon = dd
+            load_index = np.asarray(load_index)[keep]
+            params = np.asarray(params)[keep]
+            policy_idx = np.asarray(policy_idx)[keep]
+            if fault is not None:
+                fault = (fault[0], fault[1], fidx_canon[keep])
+    if dd is None:
+        return _grid_agg_distinct(load_matrix, load_index, params,
+                                  policy_idx, dt_hours, slo_limit,
+                                  slo_mode, scenario_block, devices, fault)
+    obs.count("grid.dedup.total", n)
+    obs.count("grid.dedup.kept", len(keep))
+    carry_u, agg_u = _grid_agg_distinct(
+        load_matrix, load_index, params, policy_idx, dt_hours, slo_limit,
+        slo_mode, scenario_block, devices, fault)
+    with obs.span("grid.scatter"):
+        return carry_u[inv], agg_u[inv]
+
+
+def _grid_agg_distinct(load_matrix: np.ndarray, load_index: np.ndarray,
+                       params: np.ndarray, policy_idx: np.ndarray,
+                       dt_hours: float, slo_limit: float, slo_mode: int,
+                       scenario_block: Optional[int],
+                       devices: Optional[int], fault):
+    """``_grid_agg_dispatch`` for a grid of distinct rows: the small-grid
+    scan, or the block plan, a block engine and the scatter back to grid
+    order."""
     from repro.kernels import ops
     n = len(load_index)
-    dd = _dedup_rows(load_index, params, policy_idx, fault)
-    if dd is not None:
-        keep, inv, fidx_canon = dd
-        # counters bump ONLY here: the recursive call below sees an
-        # already-distinct grid (dd None) and never double-counts
-        obs.count("grid.dedup.total", n)
-        obs.count("grid.dedup.kept", len(keep))
-        fault_k = None
-        if fault is not None:
-            fault_k = (fault[0], fault[1], fidx_canon[keep])
-        carry_u, agg_u = _grid_agg_dispatch(
-            load_matrix, np.asarray(load_index)[keep],
-            np.asarray(params)[keep], np.asarray(policy_idx)[keep],
-            dt_hours, slo_limit, slo_mode, scenario_block, devices,
-            fault_k)
-        return carry_u[inv], agg_u[inv]
     backend = "pallas" if ops.pallas_enabled() else "xla"
     # the Pallas path still stages per-block [T, B] column panels (one
     # for loads, +2 for a fault grid's caps/fmask); the device-resident
@@ -1075,48 +1086,48 @@ def _grid_agg_dispatch(load_matrix: np.ndarray, load_index: np.ndarray,
     version = registry_version()
     if scenario_block is None or (scenario_block >= n
                                   and (devices or 1) <= 1):
-        if (load_matrix.shape[0] == n
-                and np.array_equal(load_index, np.arange(n))):
-            loads_np = load_matrix      # identity map: the rows ARE the grid
-        else:
-            loads_np = np.ascontiguousarray(load_matrix[load_index])
-        caps = fmask = None
-        if fault is not None:
-            cap_m, fmask_m, fidx = fault
-            caps = jnp.asarray(np.asarray(cap_m)[fidx])
-            fmask = jnp.asarray(np.asarray(fmask_m)[fidx])
-        carry_end, agg = _grid_scan_agg(jnp.asarray(loads_np),
-                                        jnp.asarray(params),
-                                        jnp.asarray(policy_idx), version,
-                                        dt_hours, slo_limit, slo_mode,
-                                        caps=caps, fmask=fmask)
-        return (np.asarray(carry_end, np.float64),
-                np.asarray(agg, np.float64))
+        with obs.span("grid.plan"):
+            if (load_matrix.shape[0] == n
+                    and np.array_equal(load_index, np.arange(n))):
+                loads_np = load_matrix  # identity map: the rows ARE the grid
+            else:
+                loads_np = np.ascontiguousarray(load_matrix[load_index])
+            fault_np = ()
+            if fault is not None:
+                cap_m, fmask_m, fidx = fault
+                fault_np = (np.asarray(cap_m)[fidx],
+                            np.asarray(fmask_m)[fidx])
+        with obs.span("grid.upload"):
+            loads, params_d, pidx, *fault_d = _to_device(
+                loads_np, params, policy_idx, *fault_np, wait=True)
+        caps, fmask = fault_d if fault_d else (None, None)
+        with obs.span("grid.scan"):
+            carry_end, agg = _grid_scan_agg(loads, params_d, pidx, version,
+                                            dt_hours, slo_limit, slo_mode,
+                                            caps=caps, fmask=fmask)
+        with obs.span("grid.drain"):
+            return (np.asarray(carry_end, np.float64),
+                    np.asarray(agg, np.float64))
 
     block = int(min(scenario_block, max(n, 1)))
-    positions, block_policy = _agg_block_plan(policy_idx, block)
-    obs.gauge("grid.block_size", block)
-    obs.count("grid.blocks", positions.shape[0],
-              backend=backend, devices=int(devices or 1))
-
-    # stage the per-block host operands through the position map: pad
-    # slots (-1) read row 0 with zero params — discarded on scatter
-    valid = positions >= 0
-    safe = np.where(valid, positions, 0)
-    lidx = np.where(valid, np.asarray(load_index)[safe], 0) \
-        .astype(np.int32)
-    params_b = np.where(valid[..., None], np.asarray(params)[safe],
-                        0).astype(np.float32)
-    block_fault = None
-    if fault is not None:
-        cap_m, fmask_m, fidx_all = fault
-        fidx_b = np.where(valid, np.asarray(fidx_all)[safe], 0) \
-            .astype(np.int32)
-        block_fault = (np.asarray(cap_m, np.float32),
-                       np.asarray(fmask_m, np.float32), fidx_b)
-
     d = int(devices or 1)
-    if d > 1:
+    with obs.span("grid.plan"):
+        positions, block_policy = _agg_block_plan(policy_idx, block)
+        # stage the per-block host operands through the position map: pad
+        # slots (-1) read row 0 with zero params — discarded on scatter
+        valid = positions >= 0
+        safe = np.where(valid, positions, 0)
+        lidx = np.where(valid, np.asarray(load_index)[safe], 0) \
+            .astype(np.int32)
+        params_b = np.where(valid[..., None], np.asarray(params)[safe],
+                            0).astype(np.float32)
+        block_fault = None
+        if fault is not None:
+            cap_m, fmask_m, fidx_all = fault
+            fidx_b = np.where(valid, np.asarray(fidx_all)[safe], 0) \
+                .astype(np.int32)
+            block_fault = (np.asarray(cap_m, np.float32),
+                           np.asarray(fmask_m, np.float32), fidx_b)
         nb = positions.shape[0]
         pad_blocks = (-nb) % d
         if pad_blocks:      # dummy all-pad blocks so every round is full
@@ -1134,6 +1145,10 @@ def _grid_agg_dispatch(load_matrix: np.ndarray, load_index: np.ndarray,
                                    [block_fault[2],
                                     np.zeros((pad_blocks, block),
                                              np.int32)]))
+    obs.gauge("grid.block_size", block)
+    obs.count("grid.blocks", nb, backend=backend, devices=d)
+
+    if d > 1:
         carry, agg = _run_blocks_sharded(
             np.asarray(load_matrix), lidx, params_b, block_policy, d,
             version, float(dt_hours), float(slo_limit), int(slo_mode),
@@ -1146,13 +1161,14 @@ def _grid_agg_dispatch(load_matrix: np.ndarray, load_index: np.ndarray,
             version, float(dt_hours), float(slo_limit), int(slo_mode),
             backend, fault=block_fault)
 
-    # scatter block results back to grid order through the position map
-    flat_pos = positions.reshape(-1)
-    vmask = flat_pos >= 0
-    carry_end = np.zeros((n, carry.shape[-1]), np.float64)
-    out_agg = np.zeros((n, agg.shape[-1]), np.float64)
-    carry_end[flat_pos[vmask]] = carry[vmask]
-    out_agg[flat_pos[vmask]] = agg[vmask]
+    with obs.span("grid.scatter"):
+        # block results back to grid order through the position map
+        flat_pos = positions.reshape(-1)
+        vmask = flat_pos >= 0
+        carry_end = np.zeros((n, carry.shape[-1]), np.float64)
+        out_agg = np.zeros((n, agg.shape[-1]), np.float64)
+        carry_end[flat_pos[vmask]] = carry[vmask]
+        out_agg[flat_pos[vmask]] = agg[vmask]
     return carry_end, out_agg
 
 
@@ -1291,11 +1307,26 @@ def simulate_grid(twins: Sequence[Twin], loads: Optional[np.ndarray] = None,
     ``devices``, ``faulted``); the blocked aggregate engine nests a
     ``grid.block`` span per device block (``grid.round`` per sharded
     round) tagged with block index, size, policy, backend and a
-    ``compiled`` flag read off the jit trace cache, so re-trace storms
-    are visible per block. Counters: ``grid.scenarios``,
-    ``grid.blocks{backend,devices}``, ``grid.dedup.total`` /
-    ``grid.dedup.kept`` (how much of the grid bitwise-dedup collapsed).
-    All instrumentation sits at dispatch boundaries — never inside
+    ``compiled`` flag read off the jit trace cache at dispatch, so
+    re-trace storms are visible per block. A ``grid.block`` span times
+    the host's dispatch of its block and does not wait for the device
+    (a ``grid.round`` does); a block's device time is read off a
+    profiler trace. The host work around the scan has spans of its own:
+    ``grid.params`` (the twins' parameter rows, before the root opens),
+    ``grid.dedup``, ``grid.plan`` (block plan and staging, or the
+    small-grid gather), ``grid.upload`` (the host-to-device copies; with
+    telemetry on it waits for them, before any device work is queued),
+    ``grid.scan`` (the small-grid dispatch), ``grid.drain`` (the wait
+    for the results and their copy back), ``grid.scatter`` (back to
+    grid order) and ``grid.summarise`` (``GridSummary`` rows, after the
+    root closes). Every span also opens a profiler annotation of its
+    name, so under a running ``jax.profiler`` trace the spans lie on the
+    host plane beside the device's operations. Counters:
+    ``grid.scenarios``, ``grid.blocks{backend,devices}``,
+    ``grid.dedup.total`` / ``grid.dedup.kept`` (how much of the grid
+    bitwise-dedup collapsed), ``grid.h2d_bytes`` (bytes copied to the
+    device: the shared matrices and every block's operands). All
+    instrumentation sits at dispatch boundaries — never inside
     jitted code — so simulated numbers are bit-identical with telemetry
     on or off, and the disabled path costs one attribute check per
     site. ``obs.render()`` prints the consolidated table;
@@ -1365,8 +1396,9 @@ def simulate_grid(twins: Sequence[Twin], loads: Optional[np.ndarray] = None,
                 f"JAX device(s) are visible; on CPU export "
                 f"XLA_FLAGS=--xla_force_host_platform_device_count="
                 f"{devices} before the first jax import")
-    params = np.stack([tw.padded_params() for tw in twins])
-    idx = np.asarray([tw.policy_index for tw in twins], np.int32)
+    with obs.span("grid.params", n=len(twins)):
+        params = np.stack([tw.padded_params() for tw in twins])
+        idx = np.asarray([tw.policy_index for tw in twins], np.int32)
     names = list(names) if names is not None else [tw.name for tw in twins]
 
     fault = None
@@ -1419,9 +1451,11 @@ def simulate_grid(twins: Sequence[Twin], loads: Optional[np.ndarray] = None,
                 load_matrix, load_index, params, idx, float(bin_hours),
                 slo_limit, slo_mode, scenario_block, devices=devices,
                 fault=fault)
-        return _summarise_aggregates(
-            names, twins, carry_end[:, 0], agg, slo, cost_model, record_mb,
-            float(bin_hours), t_bins, load_matrix, load_index)
+        with obs.span("grid.summarise", n=n):
+            return _summarise_aggregates(
+                names, twins, carry_end[:, 0], agg, slo, cost_model,
+                record_mb, float(bin_hours), t_bins, load_matrix,
+                load_index)
 
     if loads is None:
         # series mode needs the full grid — the O(N*T) stack is the cost

@@ -20,6 +20,8 @@ from typing import Tuple
 
 import numpy as np
 
+from repro import obs
+
 HOURS_PER_YEAR = 8736            # 52 weeks, the paper's year (cost tables)
 DAYS_PER_YEAR = 364
 # calendar months over a 364-day year (Dec truncated to 30 days)
@@ -81,6 +83,7 @@ class TrafficModel:
         return (self.R * 3600.0) * growth * H[how] * M[months[day]]
 
     @staticmethod
+    @obs.instrument
     def honda_default(name: str = "nominal", R: float = 3.5,
                       G: float = 1.0) -> "TrafficModel":
         """Synthesized Honda-like factors calibrated to published anchors."""

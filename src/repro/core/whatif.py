@@ -29,6 +29,7 @@ from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from repro import obs
 from repro.core.cost import CostModel
 from repro.core.simulate import (GridSummary, SimulationResult,
                                  monthly_table, simulate_grid, simulate_year)
@@ -70,7 +71,8 @@ def run_grid(twins: Sequence[Twin], traffics: Sequence[TrafficModel],
     suites"); ``table2_rows`` then adds the fault-attribution columns."""
     if not twins or not traffics:
         return []
-    load_matrix = np.stack([tr.hourly_loads() for tr in traffics])
+    with obs.span("whatif.loads", traffics=len(traffics)):
+        load_matrix = np.stack([tr.hourly_loads() for tr in traffics])
     load_index = np.repeat(np.arange(len(traffics), dtype=np.int32),
                            len(twins))
     grid_twins = [tw for _ in traffics for tw in twins]
@@ -171,35 +173,36 @@ def run_scenarios(scenarios: Sequence[Scenario],
 
 
 def table2_rows(sims: Sequence[GridResult]) -> List[Dict]:
-    # chaos-suite grids (any row simulated through fault windows) grow
-    # three attribution columns; benign tables keep the seed's exact
-    # column set
-    fault_cols = any(getattr(s, "fault_hours", 0.0) > 0.0 for s in sims)
-    rows = []
-    for s in sims:
-        row = {
-            "run": s.name,
-            "policy": s.twin.policy,
-            "cost_usd": round(s.total_cost_usd, 2),
-            "latency_median_s": round(s.median_latency_s, 2),
-            "latency_p95_s": round(s.p95_latency_s, 2),
-            "latency_p99_s": round(s.p99_latency_s, 2),
-            "latency_mean_s": round(s.mean_latency_s, 2),
-            "latency_backlog_s": round(s.backlog_s, 2),
-            "thruput_mean_rph": round(s.mean_throughput_rph, 2),
-            "thruput_max_rph": round(s.max_throughput_rph, 2),
-            "dropped": round(s.dropped_records, 1),
-            "pct_latency_met": round(s.pct_latency_met, 2),
-            "slo_met": s.slo_met,
-        }
-        if fault_cols:
-            row["fault_hours"] = round(getattr(s, "fault_hours", 0.0), 1)
-            row["pct_hours_met_in_fault"] = round(
-                getattr(s, "pct_hours_met_in_fault", 100.0), 2)
-            row["pct_hours_met_outside_fault"] = round(
-                getattr(s, "pct_hours_met_outside_fault", 100.0), 2)
-        rows.append(row)
-    return rows
+    with obs.span("whatif.table2", n=len(sims)):
+        # chaos-suite grids (any row simulated through fault windows) grow
+        # three attribution columns; benign tables keep the seed's exact
+        # column set
+        fault_cols = any(getattr(s, "fault_hours", 0.0) > 0.0 for s in sims)
+        rows = []
+        for s in sims:
+            row = {
+                "run": s.name,
+                "policy": s.twin.policy,
+                "cost_usd": round(s.total_cost_usd, 2),
+                "latency_median_s": round(s.median_latency_s, 2),
+                "latency_p95_s": round(s.p95_latency_s, 2),
+                "latency_p99_s": round(s.p99_latency_s, 2),
+                "latency_mean_s": round(s.mean_latency_s, 2),
+                "latency_backlog_s": round(s.backlog_s, 2),
+                "thruput_mean_rph": round(s.mean_throughput_rph, 2),
+                "thruput_max_rph": round(s.max_throughput_rph, 2),
+                "dropped": round(s.dropped_records, 1),
+                "pct_latency_met": round(s.pct_latency_met, 2),
+                "slo_met": s.slo_met,
+            }
+            if fault_cols:
+                row["fault_hours"] = round(getattr(s, "fault_hours", 0.0), 1)
+                row["pct_hours_met_in_fault"] = round(
+                    getattr(s, "pct_hours_met_in_fault", 100.0), 2)
+                row["pct_hours_met_outside_fault"] = round(
+                    getattr(s, "pct_hours_met_outside_fault", 100.0), 2)
+            rows.append(row)
+        return rows
 
 
 def retention_whatif(twin: Twin, traffic: TrafficModel, record_mb: float,

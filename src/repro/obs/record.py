@@ -25,6 +25,12 @@ Design rules:
   pair at construction so exporters can place every span on the unix
   epoch — which is what lets ``ObservedTrace.from_otel_spans`` re-import
   the tool's own telemetry (see ``repro.obs.export``).
+* **On the profiler's clock too.** An enabled ``span`` (and so
+  ``instrument``) also opens a ``jax.profiler.TraceAnnotation`` of the
+  span's name: under a running profiler every span lands on the
+  ``/host:CPU`` plane, on the device planes' clock and nested in
+  whatever annotation the caller opened. With no profiler running the
+  annotation records nothing.
 """
 from __future__ import annotations
 
@@ -256,12 +262,20 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
+def _annotation(name: str):
+    """The profiler annotation an enabled span opens (jax is imported
+    here, on the enabled path only)."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
+
+
 class _OpenSpan:
     """Context manager recording one span. The span id is allocated
     eagerly on enter so nested children link to this span as parent
     while it is still open; ``attrs`` stays mutable inside the block
-    (for results known only at exit, e.g. a compile flag)."""
-    __slots__ = ("name", "attrs", "_rec", "_t0", "span")
+    (for results known only at exit, e.g. a compile flag). The span's
+    profiler annotation opens first and closes last."""
+    __slots__ = ("name", "attrs", "_rec", "_t0", "span", "_ann")
 
     def __init__(self, rec: Recorder, name: str, attrs: Dict):
         self._rec = rec
@@ -270,6 +284,8 @@ class _OpenSpan:
         self.span = None
 
     def __enter__(self):
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
         self.span = ObsSpan(self.name, 0.0, 0.0, self.attrs,
                             next(self._rec._ids))
         stack = self._rec._parents()
@@ -286,6 +302,7 @@ class _OpenSpan:
             self._rec.spans.append(self.span)
         if self._rec.retention_s is not None:
             self._rec.prune()
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -297,7 +314,8 @@ def span(name: str, **attrs):
             sp.attrs["compiled"] = 1.0
 
     Disabled, this returns a shared null context manager — the cost is
-    the enabled check plus assembling the kwargs dict.
+    the enabled check plus assembling the kwargs dict. Enabled, it also
+    opens a ``jax.profiler.TraceAnnotation`` named ``name``.
     """
     if not _ENABLED:
         return _NULL

@@ -36,7 +36,7 @@ from repro.core.simulate import (AGG_AUTO_BLOCK,  # noqa: E402
                                  simulate_grid)
 from repro.core.slo import SLO  # noqa: E402
 from repro.core.traffic import HOURS_PER_YEAR, TrafficModel  # noqa: E402
-from repro.core.twin import (AGG_DIM, CARRY_DIM,  # noqa: E402
+from repro.core.twin import (AGG_DIM, CARRY_DIM, PARAM_DIM,  # noqa: E402
                              QuickscalingTwin, SimpleTwin, make_twin,
                              registry_version)
 from repro.core.whatif import run_grid  # noqa: E402
@@ -178,6 +178,21 @@ def test_sharded_round_step_matches_uniform_scan_one_device():
     assert np.asarray(agg).shape == (1, block, AGG_DIM)  # no [B, T] output
     np.testing.assert_array_equal(np.asarray(carry[0]), np.asarray(ref_c))
     np.testing.assert_array_equal(np.asarray(agg[0]), np.asarray(ref_a))
+
+
+def test_mesh_round_has_a_stable_program_name():
+    """The round step is a jit of a named function, so a profiler trace
+    names its program ``jit__mesh_agg_round`` on any mesh."""
+    block = 8
+    _, matrix, index, _, _ = _grid_arrays(block)
+    fn = _sharded_agg_fn(1, registry_version(), 1.0, float("inf"), 0,
+                         "xla", block)
+    with jax.enable_x64(True):
+        low = fn.lower(jnp.asarray(matrix),
+                       jnp.zeros((1, block), jnp.int32),
+                       jnp.zeros((1, block, PARAM_DIM), jnp.float32),
+                       jnp.zeros((1,), jnp.int32))
+    assert "module @jit__mesh_agg_round" in low.as_text()
 
 
 # ---------------------------------------------------------------------------

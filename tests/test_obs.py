@@ -29,6 +29,7 @@ Multi-device cases need ``XLA_FLAGS=--xla_force_host_platform_device_count=4``
 exported before the first jax import (the CI obs-suite job does);
 without it they skip rather than sharding a 1-device mesh.
 """
+import dataclasses
 import json
 import time
 import warnings
@@ -298,6 +299,133 @@ def test_sharded_grid_emits_per_round_spans():
     flat = obs.counters()                    # capture() left the spans +
     key = f"grid.blocks{{backend=xla,devices={d}}}"   # counters in place
     assert flat[key] == n // block
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock: annotations, no sync in the block loop
+# ---------------------------------------------------------------------------
+
+class _Annotations:
+    """Stand-in for ``jax.profiler.TraceAnnotation``: logs each open and
+    close and keeps the stack of open annotations."""
+
+    def __init__(self):
+        self.log, self.stack = [], []
+        outer = self
+
+        class Stub:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                outer.log.append(self.name)
+                outer.stack.append(self.name)
+                return self
+
+            def __exit__(self, *exc):
+                outer.log.append("/" + outer.stack.pop())
+                return False
+
+        self.cls = Stub
+
+
+@pytest.fixture()
+def annotations(monkeypatch):
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann.cls)
+    return ann
+
+
+def test_span_opens_a_trace_annotation_only_when_on(annotations):
+    with obs.span("off"):
+        pass
+    obs.instrument(name="off.deco")(lambda: None)()
+    assert annotations.log == []
+    with obs.capture():
+        with obs.span("outer"):
+            with obs.span("inner"):
+                pass
+        obs.instrument(name="deco")(lambda: None)()
+    assert annotations.log == ["outer", "inner", "/inner", "/outer",
+                               "deco", "/deco"]
+
+
+def test_block_loop_never_syncs_inside_grid_block(annotations, monkeypatch):
+    """With obs on, the block engine waits for the device only in
+    ``grid.upload`` (its own copies, nothing queued yet) and at the drain,
+    never inside a ``grid.block`` span."""
+    real = jax.block_until_ready
+    synced_in = []
+
+    def spy(x):
+        synced_in.append(tuple(annotations.stack))
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", spy)
+    twins, matrix, index = _small_grid(n=8, distinct=8)
+    with obs.capture() as rec:
+        simulate_grid(twins, slo=SLO_4H, bin_hours=1.0, return_series=False,
+                      scenario_block=2, load_matrix=matrix,
+                      load_index=index)
+    assert annotations.log.count("grid.block") == 4
+    assert len(rec.find(name="grid.block")) == 4
+    assert not [s for s in synced_in if "grid.block" in s]
+    assert [s[-1] for s in synced_in] == ["grid.upload"]
+
+
+#: the host-side spans of one grid, by engine
+_SMALL_SPANS = ("grid.params", "grid.simulate", "grid.dedup", "grid.plan",
+                "grid.upload", "grid.scan", "grid.drain", "grid.summarise")
+_BLOCK_SPANS = ("grid.params", "grid.simulate", "grid.dedup", "grid.plan",
+                "grid.upload", "grid.drain", "grid.scatter",
+                "grid.summarise")
+
+
+@pytest.mark.parametrize("scenario_block,names,absent", [
+    (None, _SMALL_SPANS, ("grid.scatter", "grid.block")),
+    (2, _BLOCK_SPANS, ("grid.scan",))])
+def test_grid_spans_once_per_grid_and_bits_unchanged(scenario_block, names,
+                                                     absent):
+    """Each host-side span appears once per grid of distinct rows, on the
+    small-grid engine and the block engine, the bytes copied to the
+    device are counted, and the results are bit-identical with obs on
+    and off."""
+    from repro.core.twin import PARAM_DIM
+    twins, matrix, index = _small_grid(n=8, distinct=8)
+    kw = dict(slo=SLO_4H, bin_hours=1.0, return_series=False,
+              scenario_block=scenario_block, load_matrix=matrix,
+              load_index=index)
+    base = simulate_grid(twins, **kw)
+    with obs.capture() as rec:
+        instrumented = simulate_grid(twins, **kw)
+    for a, b in zip(base, instrumented):
+        for f in dataclasses.fields(a):
+            np.testing.assert_array_equal(getattr(a, f.name),
+                                          getattr(b, f.name))
+    for name in names:
+        assert len(rec.find(name=name)) == 1, name
+    for name in absent:
+        assert not rec.find(name=name), name
+    if scenario_block is None:      # the gathered [8, T] rows, in one go
+        h2d = 8 * (matrix.shape[1] * 4 + PARAM_DIM * 4 + 4)
+    else:                           # the matrix, then 4 blocks of 2 rows
+        h2d = matrix.nbytes + 4 * (2 * 4 + 2 * PARAM_DIM * 4 + 4)
+    assert rec.counter_total("grid.h2d_bytes") == h2d
+
+
+def test_whatif_query_spans():
+    from repro.core.whatif import run_grid, table2_rows
+    twins = [SimpleTwin("a", 1.9512, 0.0082, 0.15),
+             SimpleTwin("b", 6.15, 0.0703, 0.06)]
+    with obs.capture() as rec:
+        traffics = [TrafficModel.honda_default("x", G=1.2),
+                    TrafficModel.honda_default("y", G=1.5)]
+        rows = table2_rows(run_grid(twins, traffics, slo=SLO_4H))
+    assert len(rows) == 4
+    assert len(rec.find(name="traffic.honda_default")) == 2
+    for name in ("whatif.loads", "whatif.table2", "grid.simulate",
+                 "grid.summarise"):
+        assert len(rec.find(name=name)) == 1, name
 
 
 # ---------------------------------------------------------------------------
