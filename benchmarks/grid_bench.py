@@ -35,7 +35,7 @@ single-device row is the wall-clock number, measured against the prior
 serial ``lax.map`` engine recorded in ``BENCH_grid_stream.json``.
 
 ``bench_device_hist`` times the fully device-resident aggregate engine
-(the in-graph f64 ``segment_sum`` latency histogram replacing the host
+(the in-graph f64 latency histogram reduction replacing the host
 ``np.bincount`` drain, no [B, T] latency panel staged or copied off
 device, bitwise-duplicate scenario rows deduped at dispatch) — at N in
 {1024, 65536, 1048576} full-year scenarios, single-device and over a
@@ -387,7 +387,7 @@ def bench_device_hist(sizes=DEVICE_SIZES, meshes=SHARD_MESHES) -> Dict:
     engine under it no longer stages a [B, T] latency panel or drains it
     to the host for ``np.bincount`` binning — the load-weighted
     quarter-octave histogram accumulates in-graph as an exact f64
-    ``segment_sum`` per time chunk, and blocks are sized by the
+    masked reduction per time chunk, and blocks are sized by the
     panel-free footprint. The dispatch also dedups bitwise-identical
     scenario rows before simulating — this sweep's grid tiles 8 twins
     over 8 traffic ramps, so every N collapses to the same 128 distinct
@@ -473,7 +473,7 @@ def bench_device_hist(sizes=DEVICE_SIZES, meshes=SHARD_MESHES) -> Dict:
            "meshes": usable, "meshes_skipped_no_devices": skipped,
            "scenario_block": block,
            "parity": "mesh results bit-identical at the smallest N",
-           "note": "device-resident f64 segment_sum histogram, no [B,T] "
+           "note": "device-resident f64 histogram reduction, no [B,T] "
                    "panel, no host binning; the dispatch dedups bitwise-"
                    "duplicate scenario rows, and this tiled sweep "
                    "collapses to unique_scenarios distinct years per row "

@@ -18,7 +18,7 @@ engine at 65536/262144/1048576 full-year scenarios over a 1/2/4-device
 scenario mesh (writes BENCH_grid_shard.json; on the CPU run with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=4``) —
 the device-resident histogram sweep ``grid-device`` — the fully
-in-graph aggregate engine (f64 ``segment_sum`` histogram, no host
+in-graph aggregate engine (dense f64 histogram reduction, no host
 binning, duplicate scenario rows deduped at dispatch) at
 1024/65536/1048576 full-year scenarios, single-device + 1/2/4 mesh,
 plus an all-distinct control row, vs the PR 6 host-binned baseline

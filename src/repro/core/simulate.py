@@ -296,9 +296,10 @@ def _agg_scan_vmap(loads: jnp.ndarray, params: jnp.ndarray,
     ~0.5 s per 1k scenarios in scan double-buffering on CPU. Instead
     each chunk step emits its [N, chunk] latencies and folds them
     through ``core.twin.device_latency_histogram`` — an exact f64
-    ``segment_sum`` accumulated OUTSIDE the scan carry, entirely on
-    device, bit-identical to host ``np.bincount``. No [N, T] panel is
-    ever staged and nothing round-trips to the host. MUST be traced
+    dense masked reduction per (scenario, bucket) accumulated OUTSIDE
+    the scan carry, entirely on device, bit-identical to host
+    ``np.bincount``. No [N, T] panel is ever staged and nothing
+    round-trips to the host. MUST be traced
     under ``jax.enable_x64(True)`` (``_grid_scan_agg`` wraps
     its call sites). Returns (carry_end [N, CARRY_DIM],
     agg [N, AGG_DIM] f32)."""
@@ -448,7 +449,8 @@ def _grid_scan_agg_fault_xla(loads: jnp.ndarray, caps: jnp.ndarray,
               jnp.zeros((n, AGG_HIST_BINS), jnp.float64))
     (carry, fq, agg, hist), _ = jax.lax.scan(
         chunk_step, state0, (cs(loads), cs(caps), cs(fmask)))
-    carry = carry.at[:, 0].add(fq)
+    carry = jnp.concatenate([carry[:, :1] + fq[:, None], carry[:, 1:]],
+                            axis=1)
     return carry, jnp.concatenate(
         [pack_agg_scalars(agg), hist.astype(jnp.float32)], axis=-1)
 
@@ -612,7 +614,8 @@ def _agg_scan_uniform_fault(load_matrix: jnp.ndarray, lidx: jnp.ndarray,
                       jnp.zeros((b, AGG_HIST_BINS), jnp.float64))
             (carry, fq, agg, hist), _ = jax.lax.scan(
                 chunk_step, state0, (mx, cx, fx))
-            carry = carry.at[:, 0].add(fq)
+            carry = jnp.concatenate(
+                [carry[:, :1] + fq[:, None], carry[:, 1:]], axis=1)
             return carry, jnp.concatenate(
                 [pack_agg_scalars(agg), hist.astype(jnp.float32)],
                 axis=-1)
@@ -642,7 +645,7 @@ def _agg_block_step_xla(version: int, dt_hours: float, slo_limit: float,
     device memory stays at ONE chunk's gathered loads + the O(N)
     aggregates no matter how many blocks stream through; no [B, T] panel
     ever exists and nothing returns to the host until the last block.
-    Traces f64 (the histogram segment_sum) — call under
+    Traces f64 (the histogram reduction) — call under
     ``jax.enable_x64(True)``.
     Fault grids add the replicated [F, T] capacity/mask matrices + the
     block's [B] ``fidx`` gather map (appended AFTER ``offset`` so the
@@ -892,7 +895,7 @@ def _run_blocks_sharded(load_matrix: np.ndarray, lidx: np.ndarray,
     per_round = (lidx, params, block_policy) + (
         (fault[2],) if fault is not None else ())
 
-    # the XLA round jit traces f64 (in-graph histogram segment_sum) —
+    # the XLA round jit traces f64 (in-graph histogram reduction) —
     # every call must sit inside enable_x64 or jit re-traces a truncated
     # f32 variant; the Pallas round jit is pure f32 and stays outside
     ctx = (contextlib.nullcontext() if backend == "pallas"
@@ -1245,7 +1248,7 @@ def simulate_grid(twins: Sequence[Twin], loads: Optional[np.ndarray] = None,
 
     **Scaling the grid** (aggregate mode). The whole engine is
     device-resident: the quarter-octave latency histogram accumulates
-    on device next to the scan (an exact f64 ``segment_sum`` per time
+    on device next to the scan (an exact f64 masked reduction per time
     chunk on the XLA path, compensated in-kernel triples on Pallas), so
     no ``[B, T]`` latency panel is ever staged, copied to the host, or
     binned there — only O(N·AGG_DIM) aggregate rows leave the device,

@@ -464,12 +464,13 @@ def registry_version() -> int:
 #   ``np.bincount`` bit for bit;
 # * the XLA switch-scan backend keeps only the scalar statistics in the
 #   scan carry and folds each staged time-chunk of latencies through
-#   ``device_latency_histogram`` — a flat f64 ``segment_sum`` over
-#   (scenario, bucket) ids *outside* the scan carry (a per-step
-#   [N, BINS] carry costs ~0.5 s per 1k scenarios in scan
-#   double-buffering alone). The f64 adds are exact at year-grid
-#   magnitudes, so the chunked accumulation is order-independent and
-#   matches ``np.bincount`` bitwise with no host round-trip.
+#   ``device_latency_histogram`` — a dense f64 masked reduction over
+#   the chunk axis, one per (scenario, bucket), *outside* the scan
+#   carry (a per-step [N, BINS] carry costs ~0.5 s per 1k scenarios in
+#   scan double-buffering alone; a scatter of the ids is serialised on
+#   a TPU). The f64 adds are exact at year-grid magnitudes, so the
+#   chunked accumulation is order-independent and matches
+#   ``np.bincount`` bitwise with no host round-trip.
 
 AGG_HIST_BINS = 152            # quarter-octave latency buckets
 #: smallest resolvable latency: 2^-10 s ~ 0.98 ms (bucket 0 clips below)
@@ -633,25 +634,57 @@ def np_latency_histogram(latency: np.ndarray, weights: np.ndarray,
     return out
 
 
+#: bytes one time tile of the histogram's [N, tile, AGG_HIST_BINS] f64
+#: select may take where the compiler materialises it before reducing
+#: (XLA's CPU backend does; the TPU's fuses it into the reduction)
+AGG_HIST_TILE_BYTES = 256 * 2**20
+
+
+def _hist_tile(latency, weights):
+    """[N, C] latencies + [N, C] weights -> [N, AGG_HIST_BINS] f64
+    bucket sums, ``sum_t where(bucket[n, t] == k, w[n, t], 0)``."""
+    buckets = jax.lax.broadcasted_iota(jnp.int32, (1, 1, AGG_HIST_BINS), 2)
+    hit = _hist_bucket(latency)[:, :, None] == buckets
+    w = weights.astype(jnp.float64)[:, :, None]
+    return jnp.sum(jnp.where(hit, w, 0.0), axis=1)
+
+
 def device_latency_histogram(latency, weights):
     """[N, C] latencies + [N, C] weights -> [N, AGG_HIST_BINS] f64
     load-weighted histogram, entirely on device: bucket ids from the f32
-    bit pattern (``_hist_bucket``), then ONE flat ``segment_sum`` over
-    (scenario * AGG_HIST_BINS + bucket) ids in f64.
+    bit pattern (``_hist_bucket``), then dense masked reductions over
+    the chunk axis in f64 (``_hist_tile``). There is no scatter: every
+    scenario-bin costs the same straight-line vector work whatever its
+    bucket, where a scatter with colliding ids is serialised on a TPU.
+    The TPU's compiler fuses the broadcast, compare, select and reduce
+    into one reduction; XLA's CPU backend materialises the select first,
+    so the chunk goes through in time tiles of at most
+    ``AGG_HIST_TILE_BYTES`` of it (one tile at small N; on a v5e, tiles
+    of a few dozen bins also reduce faster than one of 728).
 
     MUST be traced under ``jax.enable_x64(True)`` — outside it
     the f64 cast silently truncates to f32 and bit-parity with
     ``np_latency_histogram`` is lost. The f64 adds are exact at the
     magnitudes year grids produce (bucket sums need ~35-51 bits < 53),
-    so the result is order-independent: accumulating per time chunk and
-    adding the chunk histograms reproduces numpy's per-row f64
-    ``np.bincount`` of the full series bit for bit."""
-    n = latency.shape[0]
-    seg = (jax.lax.broadcasted_iota(jnp.int32, latency.shape, 0)
-           * AGG_HIST_BINS + _hist_bucket(latency))
-    return jax.ops.segment_sum(
-        weights.astype(jnp.float64).reshape(-1), seg.reshape(-1),
-        num_segments=n * AGG_HIST_BINS).reshape(n, AGG_HIST_BINS)
+    so the result is order-independent: the reduction's order, the
+    tiles, the time chunking and the chunk histograms' sum all reproduce
+    numpy's per-row f64 ``np.bincount`` of the full series bit for bit."""
+    n, c = latency.shape
+    tile = min(max(AGG_HIST_TILE_BYTES // (n * AGG_HIST_BINS * 8), 1), c)
+    if tile == c:
+        return _hist_tile(latency, weights)
+    full = c // tile * tile
+
+    def add_tile(i, hist):
+        def cut(a):
+            return jax.lax.dynamic_slice_in_dim(a, i * tile, tile, axis=1)
+        return hist + _hist_tile(cut(latency), cut(weights))
+
+    hist = jax.lax.fori_loop(0, c // tile, add_tile,
+                             jnp.zeros((n, AGG_HIST_BINS), jnp.float64))
+    if full < c:
+        hist = hist + _hist_tile(latency[:, full:], weights[:, full:])
+    return hist
 
 
 def init_agg_scalars(shape=()):
