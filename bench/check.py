@@ -32,7 +32,8 @@ from bench import reference
 NUMBERS = ("value_gap", "hours_gap", "pct_gap", "hist_gap")
 #: what a number reads where answers are missing
 MISSING = 1e9
-#: rows the reference plays at once (bounds its host memory)
+#: rows the reference plays at once over the hourly year; ``block_rows``
+#: scales it to the horizon, so a block's host memory stays the same
 BLOCK_ROWS = 2048
 
 #: (reference key, floor) of each compared value; the floor ``"load"`` is
@@ -63,20 +64,31 @@ def load_limits(bench_dir: str) -> Dict[str, float]:
         return {k: float(v) for k, v in json.load(f)["limits"].items()}
 
 
-def reference_rows(rows, cfg: Dict, dtype=np.float32) -> Dict[str, np.ndarray]:
+def block_rows(t_bins: int) -> int:
+    """Rows the reference plays at once over ``t_bins`` bins: as many
+    scenario-bins as ``BLOCK_ROWS`` rows of the hourly year, at least
+    one row."""
+    return max(1, min(BLOCK_ROWS, BLOCK_ROWS * reference.HOURS_PER_YEAR
+                      // int(t_bins)))
+
+
+def reference_rows(rows, cfg: Dict, dtype=np.float32,
+                   block: Optional[int] = None) -> Dict[str, np.ndarray]:
     """Reference Table II values of ``rows`` (a ``generator.Rows``), in
-    their order, computed per policy and fault layout."""
+    their order, computed per policy and fault layout, ``block`` rows at
+    a time (``block_rows`` of the horizon where None). A row's numbers do
+    not depend on the block it is played in."""
     n = len(rows.policy)
-    t_bins, bin_hours = cfg["horizon_bins"], cfg["bin_hours"]
-    slo = cfg["slo"]
+    if block is None:
+        block = block_rows(cfg["horizon_bins"])
     out: Dict[str, np.ndarray] = {}
     groups: Dict[tuple, List[int]] = {}
     for i in range(n):
         groups.setdefault((rows.policy[i], rows.future[i] is not None),
                           []).append(i)
     for (policy, faulted), members in groups.items():
-        for at in range(0, len(members), BLOCK_ROWS):
-            _reference_block(rows, members[at:at + BLOCK_ROWS], policy,
+        for at in range(0, len(members), block):
+            _reference_block(rows, members[at:at + block], policy,
                              faulted, cfg, dtype, out, n)
     return out
 

@@ -10,9 +10,11 @@ A mix names its ``request`` kind and the sizes of each request:
   configuration's order; with ``futures`` > 1 every base row plays that
   many fault futures of the configuration's ``faults`` schedule, and
   ``devices`` shards the sweep over a scenario mesh. The load rows are
-  made once, at set-up; each request pairs them with new twins (and new
-  futures) in a new order, so no two requests are alike and every one has
-  the same sizes.
+  made once, at set-up, at the configuration's ``bin_hours``: one per
+  base row, or with ``load_pool`` K a pool of K rows that the base rows
+  share, each pool row played by about rows / K of them. Each request
+  pairs them with new twins (and new futures) in a new order, so no two
+  requests are alike and every one has the same sizes.
 * ``"whatif"`` — an analyst's what-if query: ``whatif.run_grid`` over the
   configuration's paper ``variants`` x ``traffic_cases`` Honda traffic
   cases whose R and G are drawn from the mix's ranges, then
@@ -44,19 +46,41 @@ def rng_for(seed: int, *keys: int) -> np.random.Generator:
 # seeded data (the load-uncertainty Monte Carlo and the twin sweep space)
 # ---------------------------------------------------------------------------
 
-def load_rows(n: int, t_bins: int, spec: Dict, rng: np.random.Generator
-              ) -> np.ndarray:
-    """[n, t_bins] float32 load matrix whose rows are all distinct: the
+def bins_per_hour(bin_hours: float) -> int:
+    """Bins in an hour, or ``ValueError`` where ``bin_hours`` does not
+    divide the hour."""
+    per_hour = round(1.0 / bin_hours) if bin_hours > 0 else 0
+    if per_hour < 1 or abs(per_hour * bin_hours - 1.0) > 1e-12:
+        raise ValueError(f"bin_hours {bin_hours!r} does not divide the "
+                         f"hour")
+    return per_hour
+
+
+def load_rows(n: int, t_bins: int, bin_hours: float, spec: Dict,
+              rng: np.random.Generator) -> np.ndarray:
+    """[n, t_bins] float32 records per bin, rows all distinct: the
     ``curves`` Honda growth curves (R ``base_rps``, G spread over
-    ``growth``), each row scaled by its own factor drawn from ``scale``
-    and by per-bin log-normal noise of ``noise_sigma``."""
+    ``growth``) over the hours the horizon covers, each hour spread evenly
+    over its bins, each row scaled by its own factor drawn from ``scale``
+    and by per-bin log-normal noise of ``noise_sigma``. ``ValueError`` where
+    ``bin_hours`` does not divide the hour or the horizon is longer than
+    the Honda year."""
+    per_hour = bins_per_hour(bin_hours)
+    hours = -(-t_bins // per_hour)
+    if hours > reference.HOURS_PER_YEAR:
+        raise ValueError(f"{t_bins} bins of {bin_hours!r} h are longer "
+                         f"than the {reference.HOURS_PER_YEAR}-hour year")
     g = np.linspace(spec["growth"][0], spec["growth"][1], spec["curves"])
-    base = reference.honda_loads([spec["base_rps"]] * len(g), g)[:, :t_bins]
+    base = reference.honda_loads([spec["base_rps"]] * len(g), g)[:, :hours]
+    if per_hour > 1:
+        base = np.repeat(base * bin_hours, per_hour, axis=1)[:, :t_bins]
     base = base.astype(np.float32)
     out = rng.standard_normal((n, t_bins), dtype=np.float32)
     out *= np.float32(spec["noise_sigma"])
     np.exp(out, out=out)
-    out *= base[np.arange(n) % len(g)]
+    # out *= base[arange(n) % len(g)], without an [n, t_bins] copy
+    for c in range(len(g)):
+        out[c::len(g)] *= base[c]
     out *= rng.uniform(*spec["scale"], (n, 1)).astype(np.float32)
     return out
 
@@ -186,7 +210,11 @@ class SweepTraffic:
         if mix["rows"] % self.futures:
             raise ValueError("rows must be a multiple of futures")
         self.n_base = mix["rows"] // self.futures
-        self.loads = load_rows(self.n_base, self.t_bins,
+        self.pool = int(mix.get("load_pool", self.n_base))
+        if not 1 <= self.pool <= self.n_base:
+            raise ValueError(f"load_pool {self.pool} is not in 1.."
+                             f"{self.n_base} (rows / futures)")
+        self.loads = load_rows(self.pool, self.t_bins, self.bin_hours,
                                cfg["load_uncertainty"],
                                rng_for(seed, SETUP))
         self.slo = api.slo(cfg["slo"])
@@ -194,7 +222,7 @@ class SweepTraffic:
     def make(self, stream: int, index: int) -> Request:
         rng = rng_for(self.seed, stream, index)
         n, p = self.n_base, len(self.policies)
-        perm = rng.permutation(n).astype(np.int32)
+        perm = (rng.permutation(n) % self.pool).astype(np.int32)
         params = twin_params(n, self.policies, self.cfg["sweep_space"], rng)
         twins = [None] * n
         for j, policy in enumerate(self.policies):
@@ -245,6 +273,9 @@ class WhatifTraffic:
 
     def __init__(self, cfg: Dict, mix: Dict, seed: int, api):
         self.cfg, self.mix, self.seed, self.api = cfg, mix, seed, api
+        if cfg["bin_hours"] != 1.0:
+            raise ValueError("a what-if query plays the Honda model's "
+                             "hourly year: bin_hours must be 1")
         self.t_bins = cfg["horizon_bins"]
         self.names = list(cfg["variants"])
         self.variants = [cfg["variants"][k] for k in self.names]

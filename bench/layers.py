@@ -17,12 +17,11 @@ from bench import trace_reduce as tr
 
 #: the wind tunnel's scan programs, by the name the profiler gives their
 #: runs (``jit_<function>``): the block steps of the block engine, the
-#: unchunked small-grid engine, and the scenario mesh's round step (a
-#: ``shard_map`` of ``body``)
+#: unchunked small-grid engine, and the scenario mesh's round step
 SCAN_PROGRAMS = (
     "jit__agg_block_step_xla", "jit__agg_block_step_pallas",
     "jit__grid_scan_agg_xla", "jit__grid_scan_agg_fault_xla",
-    "jit__policy_agg", "jit__policy_agg_fault", "jit_body",
+    "jit__policy_agg", "jit__policy_agg_fault", "jit__mesh_agg_round",
 )
 
 

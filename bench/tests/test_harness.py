@@ -37,37 +37,52 @@ def test_last_line_form_and_correct(root, cell):
                for line in lines[-4:])
 
 
-def test_a_cell_added_by_files_and_entries_alone(root, tmp_path):
+#: (configuration changes, mix) of each cell added by files alone: two
+#: policies of the two-week year; two days of one-minute bins (2,880)
+#: with a pool of 6 load rows that 30 rows share
+ADDED_CELLS = {
+    "lean": (dict(policies=["fifo", "shed"]),
+             dict(request="sweep", rows=24, futures=1, scenario_block=8)),
+    "minute-pool": (dict(horizon_bins=2880, bin_hours=1.0 / 60),
+                    dict(request="sweep", rows=30, load_pool=6,
+                         scenario_block=8, check_requests=2)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ADDED_CELLS))
+def test_a_cell_added_by_files_and_entries_alone(root, tmp_path, case):
     """A new configuration, mix and per-layer metric: three files and
     three entries, no code changed."""
     import shutil
+    changes, mix = ADDED_CELLS[case]
     new = str(tmp_path / "root")
     shutil.copytree(root, new)
     b = os.path.join(new, "bench")
     cfg = json.load(open(os.path.join(b, "configs", "t-year.json")))
-    cfg.update(name="t-year-lean", policies=["fifo", "shed"])
-    json.dump(cfg, open(os.path.join(b, "configs", "t-year-lean.json"), "w"))
-    json.dump(dict(request="sweep", rows=24, futures=1, scenario_block=8),
-              open(os.path.join(b, "traffic", "t-lean.json"), "w"))
+    cfg.update(changes, name=f"t-{case}")
+    json.dump(cfg, open(os.path.join(b, "configs", f"t-{case}.json"), "w"))
+    json.dump(mix, open(os.path.join(b, "traffic", f"t-{case}.json"), "w"))
     with open(os.path.join(b, "metrics", "rows_per_sweep.py"), "w") as f:
         f.write("from bench import layers\n\n\n"
                 "def read(ctx):\n"
                 "    return float(sum(r.rows for r in "
                 "layers.done(ctx, 'sweep')))\n")
     spec = json.load(open(os.path.join(new, "BENCHMARK.json")))
-    spec["configs"].append(dict(name="t-year-lean", source="test",
-                                file="bench/configs/t-year-lean.json",
+    spec["configs"].append(dict(name=f"t-{case}", source="test",
+                                file=f"bench/configs/t-{case}.json",
                                 reduced=[], why="test"))
-    spec["workloads"].append(dict(name="t-lean", config="t-year-lean",
-                                  traffic="t-lean", chips=1, why="test"))
+    spec["workloads"].append(dict(name=f"t-{case}", config=f"t-{case}",
+                                  traffic=f"t-{case}", chips=1, why="test"))
     spec["end_to_end"].append(dict(name="rows_per_sweep", unit="rows",
                                    better="higher", bound=0.01,
                                    source="host_clock",
-                                   workloads=["t-lean"]))
+                                   workloads=[f"t-{case}"]))
     json.dump(spec, open(os.path.join(new, "BENCHMARK.json"), "w"))
-    res, _, _ = tiny.run(new, "t-lean")
-    assert res["correct"] is True
-    assert res["metrics"]["rows_per_sweep"]["value"] >= 24
+    res, _, _ = tiny.run(new, f"t-{case}")
+    assert res["correct"] is True, res["checks"]
+    assert res["metrics"]["rows_per_sweep"]["value"] >= mix["rows"]
+    assert res["checks"]["hours_gap"]["value"] == 0.0
+    assert res["checks"]["pct_gap"]["value"] == 0.0
 
 
 def _bench_run(cwd, env_extra=None):

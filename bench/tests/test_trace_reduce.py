@@ -100,3 +100,24 @@ def test_layer_readers_on_the_trace(trace):
         [325e-9, 50e-9, 50e-9])
     assert [n for n, _ in b["idle_gaps"]] == [
         "sweep: inner", "sweep: after the last op"]
+
+
+def test_the_mesh_round_is_read_as_scan():
+    """A four-chip sweep whose device time is the scenario mesh's round
+    step: each chip runs ``jit__mesh_agg_round`` for 300 of its 400 ns and
+    a copy for 50; ``scan_device_s`` counts the rounds alone."""
+    from bench import layers
+    planes = [{"name": f"/device:TPU:{i}", "lines": [
+        {"name": "XLA Modules", "events": [
+            [f"jit__mesh_agg_round({90 + i})", 0, 200],
+            [f"jit__mesh_agg_round({90 + i})", 250, 100],
+            ["jit_convert_element_type(3)", 360, 50]]}]}
+        for i in range(4)]
+    t = layers.Traced(devices=tr.device_planes({"planes": planes}),
+                      requests=[("sweep", 0.0, 400.0)])
+    ctx = layers.Context(cell={}, cfg={}, mix={}, chips=4, requests=[],
+                         setup_s=1.0, window_s=1.0, traced=t)
+    assert "jit__mesh_agg_round" in layers.SCAN_PROGRAMS
+    assert layers.scan_device_s(ctx, "sweep") == pytest.approx(300e-9)
+    ops = dict(layers.breakdown(t)["device_ops"])
+    assert ops["(scan names matched none)"] == pytest.approx(40e-9)
